@@ -17,10 +17,17 @@ carries mean/std/ci95/n (src/repro/core/stats.py).
 """
 from __future__ import annotations
 
+import contextlib
 import copy
 import functools
+import io
+import json
 import os
+import subprocess
+import sys
 import time
+
+import jax
 
 from repro.core.abm import ABMConfig
 from repro.core.engine import EngineConfig
@@ -28,8 +35,53 @@ from repro.core.heuristics import HeuristicConfig
 from repro.core.service import Engine
 from repro.core.stats import replica_stats, summarize
 
-RESULTS = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "results", "paper")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RESULTS = os.path.join(REPO, "results", "paper")
+
+
+def on_accelerator() -> bool:
+    """True when this process's JAX runs on an accelerator. It then
+    holds the chips: a child process that needs one fails or hangs."""
+    return jax.default_backend() != "cpu"
+
+
+def run_child(code: str, n_dev: int) -> dict:
+    """Run a benchmark section on `n_dev` devices and return the dict
+    its code prints on a `RESULT <json>` line.
+
+    On the CPU the code runs in a child process with `n_dev` forced host
+    devices (the count is fixed when jax first starts). On an
+    accelerator this process holds the chips, so the code runs here, on
+    the first `n_dev` real devices, or the call refuses."""
+    if on_accelerator():
+        if jax.device_count() < n_dev:
+            raise RuntimeError(
+                f"section needs {n_dev} devices, this host has "
+                f"{jax.device_count()} {jax.default_backend()} devices")
+        buf = io.StringIO()
+        with contextlib.redirect_stdout(buf):
+            exec(compile(code, "<benchmark section>", "exec"),
+                 {"__name__": "__section__"})
+        out = buf.getvalue()
+    else:
+        env = dict(
+            os.environ,
+            PYTHONPATH=os.pathsep.join(
+                [os.path.join(REPO, "src"), REPO,
+                 os.environ.get("PYTHONPATH", "")]),
+            XLA_FLAGS=f"--xla_force_host_platform_device_count={n_dev}",
+            XLA_PYTHON_CLIENT_PREALLOCATE="false",
+        )
+        r = subprocess.run([sys.executable, "-c", code],
+                           capture_output=True, text=True, timeout=3600,
+                           env=env)
+        if r.returncode != 0:
+            raise RuntimeError(r.stdout + r.stderr)
+        out = r.stdout
+    for line in out.splitlines():
+        if line.startswith("RESULT "):
+            return json.loads(line[len("RESULT "):])
+    raise RuntimeError(f"no RESULT line in: {out!r}")
 
 
 SCALES = {

@@ -14,12 +14,12 @@ Measures the ISSUE-9 observability acceptance bars:
    the drained ledger must reproduce the series it mirrors. Asserted
    here so the nightly gate re-proves it at bench scale, not just at
    test scale (tests/test_obs.py).
-3. **Trace export** — a 2-device subprocess traces a short sharded run
-   phase-by-phase and writes a Chrome-trace/Perfetto JSON
-   (results/exp10_trace.json, CI artifact); the parent validates the
-   timeline structure (per-device rows, step-phase spans). The events
-   JSONL from the overhead run lands next to it
-   (results/exp10_events.jsonl).
+3. **Trace export** — a 2-device section (benchmarks.common.run_child)
+   traces a short sharded run phase-by-phase and writes a
+   Chrome-trace/Perfetto JSON (results/exp10_trace.json, CI artifact);
+   the caller validates the timeline structure (per-device rows,
+   step-phase spans). The events JSONL from the overhead run lands next
+   to it (results/exp10_events.jsonl).
 
 Results land in BENCH_obs.json; `obs.overhead_ratio` is tracked by
 benchmarks/compare.py against BENCH_baseline/ (a time/time ratio —
@@ -32,7 +32,6 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -42,7 +41,8 @@ if __package__ in (None, ""):  # script invocation: python benchmarks/...
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from benchmarks.common import engine_cfg  # noqa: E402
+from benchmarks.common import engine_cfg, run_child  # noqa: E402
+from repro.compile_cache import use_compile_cache  # noqa: E402
 from repro.core.service import Engine  # noqa: E402
 from repro.obs import ObsConfig, Telemetry, runtime  # noqa: E402
 
@@ -128,7 +128,7 @@ def overhead_section(scale: str):
     }
 
 
-# 2-device child (exp5 protocol): trace a short sharded run phase-by-
+# 2-device section (run_child protocol): trace a short sharded run phase-by-
 # phase and save the Perfetto JSON; RESULT carries the phase summary.
 _TRACE_CODE = """
 import dataclasses, json
@@ -147,31 +147,12 @@ print("RESULT " + json.dumps({{
 """
 
 
-def _run_child(code: str, n_dev: int) -> dict:
-    env = dict(
-        os.environ,
-        PYTHONPATH=os.pathsep.join(
-            [os.path.join(REPO, "src"), REPO,
-             os.environ.get("PYTHONPATH", "")]),
-        XLA_FLAGS=f"--xla_force_host_platform_device_count={n_dev}",
-        XLA_PYTHON_CLIENT_PREALLOCATE="false",
-    )
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=3600, env=env)
-    if r.returncode != 0:
-        raise RuntimeError(r.stdout + r.stderr)
-    for line in r.stdout.splitlines():
-        if line.startswith("RESULT "):
-            return json.loads(line[len("RESULT "):])
-    raise RuntimeError(f"no RESULT line in: {r.stdout!r}")
-
-
 def trace_section():
-    """Sharded step-phase timeline in a TRACE_DEVS-device subprocess;
+    """Sharded step-phase timeline on TRACE_DEVS devices (run_child);
     the parent re-opens the saved JSON and validates the Perfetto
     structure it promises CI consumers."""
     os.makedirs(RESULTS_DIR, exist_ok=True)
-    row = _run_child(
+    row = run_child(
         _TRACE_CODE.format(steps=TRACE_STEPS, n_dev=TRACE_DEVS,
                            out=TRACE_OUT),
         TRACE_DEVS)
@@ -232,4 +213,5 @@ if __name__ == "__main__":
     ap.add_argument("scale", nargs="?", default="quick",
                     choices=["quick", "full"])
     a = ap.parse_args()
+    print(f"[exp10] compile cache: {use_compile_cache()}")
     main(a.scale)

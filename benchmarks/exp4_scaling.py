@@ -189,5 +189,7 @@ def main(scale: str = "quick"):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    print(f"[exp4] compile cache: {use_compile_cache()}")
     import sys
     main(sys.argv[1] if len(sys.argv) > 1 else "quick")

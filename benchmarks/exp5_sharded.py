@@ -34,11 +34,14 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 
-SRC = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "src")
+if __package__ in (None, ""):  # script invocation: python benchmarks/...
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+from benchmarks.common import run_child  # noqa: E402
+from repro.compile_cache import use_compile_cache  # noqa: E402
+
 OUT = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "BENCH_sharded.json")
 
@@ -148,39 +151,22 @@ print("RESULT " + json.dumps(rows))
 """
 
 
-def _run_child(code: str, n_dev: int) -> dict:
-    env = dict(
-        os.environ,
-        PYTHONPATH=SRC + os.pathsep + os.environ.get("PYTHONPATH", ""),
-        XLA_FLAGS=f"--xla_force_host_platform_device_count={n_dev}",
-        XLA_PYTHON_CLIENT_PREALLOCATE="false",
-    )
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=3600, env=env)
-    if r.returncode != 0:
-        raise RuntimeError(r.stdout + r.stderr)
-    for line in r.stdout.splitlines():
-        if line.startswith("RESULT "):
-            return json.loads(line[len("RESULT "):])
-    raise RuntimeError(f"no RESULT line in: {r.stdout!r}")
-
-
 def main(scale: str = "full"):
     n_se = SCALES[scale]
     rows = []
-    row = _run_child(_TIMING_CODE.format(mode="none", n_dev=1, n_se=n_se,
-                                         steps=STEPS), 1)
+    row = run_child(_TIMING_CODE.format(mode="none", n_dev=1, n_se=n_se,
+                                        steps=STEPS), 1)
     print(f"[exp5] none      D=1 {row['per_step_s']:.3f}s/step")
     rows.append(row)
     for d in DEVICE_COUNTS:
-        row = _run_child(_TIMING_CODE.format(mode="lp_device", n_dev=d,
-                                             n_se=n_se, steps=STEPS), d)
+        row = run_child(_TIMING_CODE.format(mode="lp_device", n_dev=d,
+                                            n_se=n_se, steps=STEPS), d)
         print(f"[exp5] lp_device D={d} {row['per_step_s']:.3f}s/step "
               f"(halo_frac {row['halo_frac']}, overflow {row['overflow']})")
         assert row["overflow"] == 0.0, row
         rows.append(row)
 
-    halo = _run_child(_HALO_CODE.format(n_se=min(n_se, 10_000)), 4)
+    halo = run_child(_HALO_CODE.format(n_se=min(n_se, 10_000)), 4)
     g_on = halo["gaia_on"]
     print(f"[exp5] halo shrink (D=4 hotspot, GAIA on): "
           f"{g_on['halo_frac_first10']['mean']} -> "
@@ -228,4 +214,5 @@ def main(scale: str = "full"):
 
 
 if __name__ == "__main__":
+    print(f"[exp5] compile cache: {use_compile_cache()}")
     main(sys.argv[1] if len(sys.argv) > 1 else "full")

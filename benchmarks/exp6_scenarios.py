@@ -275,6 +275,8 @@ def main(scale: str = "quick", replicas=None):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    print(f"[exp6] compile cache: {use_compile_cache()}")
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("scale", nargs="?", default="quick",

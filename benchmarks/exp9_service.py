@@ -15,8 +15,9 @@ Two claims behind the PR-8 service API are measured here:
    integer counters are bit-exact; see tests/test_service.py).
 3. **Sharded churn** — the same open-world churn loop on the
    `sharding="lp_device"` layer (arrivals packed into per-device free
-   slots, departures located by global id): measured in a 2-device
-   subprocess (the main process owns a different device topology) and
+   slots, departures located by global id): measured on 2 devices
+   (benchmarks.common.run_child: a child process with forced host
+   devices on the CPU, this process's chips on an accelerator) and
    reported as arrivals+departures/s next to the oracle's number. No
    absolute gate — the sharded layer pays per-device slot bookkeeping
    for its memory locality, and the number is machine-sized; it is
@@ -42,7 +43,6 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -52,7 +52,8 @@ if __package__ in (None, ""):  # script invocation: python benchmarks/...
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from benchmarks.common import engine_cfg  # noqa: E402
+from benchmarks.common import engine_cfg, run_child  # noqa: E402
+from repro.compile_cache import use_compile_cache  # noqa: E402
 from repro.core.service import Engine, ReplicaService  # noqa: E402
 from repro.core.stats import percentile  # noqa: E402
 
@@ -129,7 +130,7 @@ def churn_section(scale: str):
 SHARDED_DEVS = 2
 SHARDED_ITERS = {"quick": 25, "full": 60}
 
-# child process template (exp5 protocol: own XLA device topology, one
+# section template (run_child protocol: own device topology, one
 # RESULT line on stdout). Runs the churn_section loop on the sharded
 # layer: depart CHURN_BATCH by global id, admit CHURN_BATCH into
 # per-device free slots, advance one step.
@@ -168,32 +169,11 @@ print("RESULT " + json.dumps({{
 }}))
 """
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _run_child(code: str, n_dev: int) -> dict:
-    env = dict(
-        os.environ,
-        PYTHONPATH=os.pathsep.join(
-            [os.path.join(_REPO, "src"), _REPO,
-             os.environ.get("PYTHONPATH", "")]),
-        XLA_FLAGS=f"--xla_force_host_platform_device_count={n_dev}",
-        XLA_PYTHON_CLIENT_PREALLOCATE="false",
-    )
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=3600, env=env)
-    if r.returncode != 0:
-        raise RuntimeError(r.stdout + r.stderr)
-    for line in r.stdout.splitlines():
-        if line.startswith("RESULT "):
-            return json.loads(line[len("RESULT "):])
-    raise RuntimeError(f"no RESULT line in: {r.stdout!r}")
-
 
 def sharded_churn_section(scale: str):
-    """Churn loop on the LP-per-device layer, in a SHARDED_DEVS-device
-    subprocess."""
-    row = _run_child(
+    """Churn loop on the LP-per-device layer, on SHARDED_DEVS devices
+    (see benchmarks.common.run_child)."""
+    row = run_child(
         _SHARDED_CHURN_CODE.format(batch=CHURN_BATCH,
                                    iters=SHARDED_ITERS[scale],
                                    n_dev=SHARDED_DEVS),
@@ -318,4 +298,5 @@ if __name__ == "__main__":
     ap.add_argument("scale", nargs="?", default="quick",
                     choices=["quick", "full"])
     a = ap.parse_args()
+    print(f"[exp9] compile cache: {use_compile_cache()}")
     main(a.scale)

@@ -175,7 +175,9 @@ class LocalExecutor(Executor):
     """One subprocess per cell on this host, at most `workers` alive at
     once. Each child gets its own XLA runtime with the cell's forced
     host-device count — the only way to vary the device mesh per cell,
-    since a process's device count is fixed at first jax import."""
+    since a process's device count is fixed at first jax import. The
+    children are CPU processes by design: on an accelerator this process
+    holds the chips, and `run` refuses."""
 
     kind = "local"
 
@@ -197,6 +199,13 @@ class LocalExecutor(Executor):
             text=True, env=env)
 
     def run(self, cells: list) -> list:
+        from benchmarks.common import on_accelerator
+        if on_accelerator():
+            raise RuntimeError(
+                "the local fleet executor starts one process per cell with "
+                "its own forced host-device count, but this process holds "
+                "the accelerator, so a child would fail or hang. Run the "
+                "fleet on the CPU (JAX_PLATFORMS=cpu)")
         pending = list(enumerate(cells))
         live: dict = {}  # index -> (cell, proc, t0)
         results: list = [None] * len(cells)
@@ -337,7 +346,10 @@ if __name__ == "__main__":
                     help="(internal) run one cell payload and print "
                          "its RESULT line")
     a = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    cache_dir = use_compile_cache()
     if a.cell is not None:
         print("RESULT " + json.dumps(run_cell_payload(json.loads(a.cell))))
     else:
+        print(f"[fleet] compile cache: {cache_dir}")
         main(a.scale, a.replicas, a.executor, a.workers)

@@ -70,4 +70,6 @@ def main(scale: str = "quick", steps=600, drift_every=200):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    print(f"[gaiamoe] compile cache: {use_compile_cache()}")
     main()

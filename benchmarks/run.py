@@ -63,7 +63,10 @@ def trace_main(n_steps: int) -> int:
     import jax
 
     from benchmarks.common import engine_cfg
+    from repro.compile_cache import use_compile_cache
     from repro.obs import trace_run
+
+    print(f"[trace] compile cache: {use_compile_cache()}")
 
     results = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "results")
@@ -105,6 +108,8 @@ def main() -> int:
     if args.trace is not None:
         return trace_main(args.trace)
 
+    from repro.compile_cache import use_compile_cache
+    print(f"[run] compile cache: {use_compile_cache()}")
     from benchmarks import (exp1_speed, exp2_lps, exp3_range, exp4_scaling,
                             exp5_sharded, exp6_scenarios, exp7_partition,
                             exp8_replicas, exp9_service, exp10_obs, fleet,

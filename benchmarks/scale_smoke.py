@@ -132,4 +132,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    print(f"[scale] compile cache: {use_compile_cache()}")
     main()

@@ -61,4 +61,6 @@ def main(scale: str = "quick"):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    print(f"[selftune] compile cache: {use_compile_cache()}")
     main()

@@ -17,8 +17,11 @@ Subpackages:
 - flash_decode: single-token decode attention with GQA
 - moe_gate: fused top-k gating for the MoE layer
 
-All kernels accept `interpret=True` (the default used in tests and on
-CPU): the kernel body executes per tile on the host, which checks
-correctness everywhere but is slow — never benchmark interpret mode
-(DESIGN.md §Adaptations, interpret-mode caveat).
+Interpret mode is the CPU lowering only. The proximity kernels pick it
+in proximity/ops.py: the Pallas interpreter when lowering for the CPU
+(correct but slow — never benchmark it), the compiled Mosaic kernel
+when lowering for a TPU (DESIGN.md §Interpret-mode caveat). The
+beyond-paper kernels (flash_attention, flash_decode, moe_gate) are off
+the engine's path; they take `interpret` as a required keyword, so no
+caller gets the interpreter without asking for it.
 """

@@ -65,7 +65,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 @functools.partial(jax.jit,
                    static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention(q, k, v, causal: bool = True, bq: int = 256,
-                    bk: int = 512, interpret: bool = True):
+                    bk: int = 512, *, interpret: bool):
     """q,k,v: (BH, S, D) with KV already group-expanded. Returns (BH,S,D)."""
     import math
     BH, S, D = q.shape

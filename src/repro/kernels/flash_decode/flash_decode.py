@@ -58,7 +58,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
 def flash_decode(q, k_cache, v_cache, pos, bk: int = 512,
-                 interpret: bool = True):
+                 *, interpret: bool):
     """q: (B, Hq, D); caches: (B, S, Hkv, D); pos: scalar int32.
 
     Returns (B, Hq, D)."""

@@ -53,7 +53,7 @@ def _kernel(logits_ref, bias_ref, top_p_ref, top_e_ref, counts_ref, *,
 @functools.partial(jax.jit, static_argnames=("k", "norm_topk", "bt",
                                              "interpret"))
 def moe_gate(logits, k: int, bias=None, norm_topk: bool = True,
-             bt: int = 512, interpret: bool = True):
+             bt: int = 512, *, interpret: bool):
     """logits: (T, E) f32 -> (top_p (T,k) f32, top_e (T,k) i32,
     counts (E,) i32)."""
     T, E = logits.shape

@@ -60,7 +60,7 @@ def _kernel(px_ref, py_ref, sender_ref, cx_ref, cy_ref, clp_ref, valid_ref,
                                     "interpret"))
 def proximity_lp_counts_grid(pos, lp, sender_mask, n_lp: int, area: float,
                              rng: float, spec: GridSpec,
-                             interpret: bool = True):
+                             interpret: bool):
     """Grid-candidate twin of proximity_lp_counts — bit-identical counts.
 
     `spec` must satisfy the cell-list contract (cell side >= rng,

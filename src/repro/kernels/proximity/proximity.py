@@ -48,7 +48,7 @@ def _kernel(pos_i_ref, pos_j_ref, lp_onehot_ref, sender_ref, iota_i_ref,
 @functools.partial(jax.jit, static_argnames=("n_lp", "area", "rng",
                                              "interpret"))
 def proximity_lp_counts(pos, lp, sender_mask, n_lp: int, area: float,
-                        rng: float, interpret: bool = True):
+                        rng: float, interpret: bool):
     n = pos.shape[0]
     bi = bj = min(BI, n)
     pad = -n % bi

@@ -18,7 +18,6 @@ from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 BLOCK = 256
@@ -78,6 +77,6 @@ def q8_cross_pod_mean(grads: Any, err: Any, mesh, pod_axis: str = "pod"):
         return mean, new_e
 
     spec = P(pod_axis)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                   out_specs=(spec, spec), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                       out_specs=(spec, spec), check_vma=False)
     return fn(grads, err)

@@ -72,7 +72,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import balance as bal
@@ -157,9 +156,12 @@ def make_shard_spec(cfg) -> ShardSpec:
     avail = len(jax.devices())
     d = cfg.n_devices if cfg.n_devices > 0 else avail
     if d > avail:
-        raise ValueError(f"n_devices={d} but only {avail} JAX devices are "
-                         "visible (XLA_FLAGS=--xla_force_host_platform_"
-                         "device_count=... must be set before jax init)")
+        raise ValueError(
+            f"n_devices={d} but only {avail} JAX devices are visible. On "
+            "the CPU, XLA_FLAGS=--xla_force_host_platform_device_count=N "
+            "forces N host devices (set it before jax is imported); on an "
+            "accelerator the host has only the chips it has — lower "
+            "n_devices (0 = all visible)")
     d = min(d, L)  # never more devices than LPs
     backend = abm.resolved_backend()
     if backend.startswith("pallas"):
@@ -1081,8 +1083,8 @@ def _wrap_phase(fn, in_keys, out_keys, cfg, spec: ShardSpec, mesh: Mesh):
         return {k: (out[k].reshape((1,)) if k in _PER_DEV else out[k])
                 for k in out_keys}
 
-    return jax.jit(shard_map(inner, mesh=mesh, in_specs=(in_specs,),
-                             out_specs=out_specs, check_rep=False))
+    return jax.jit(jax.shard_map(inner, mesh=mesh, in_specs=(in_specs,),
+                                 out_specs=out_specs, check_vma=False))
 
 
 def sharded_trace_phases(cfg, spec: ShardSpec, mesh: Mesh):
@@ -1113,12 +1115,12 @@ def step_sharded(state, cfg, spec: ShardSpec, mesh: Mesh, mf=None):
     key, k_move, k_send = jax.random.split(state["key"], 3)
     fspecs = _field_specs(spec)
     fields = {k: state[k] for k in fspecs}
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_shard_step, cfg=cfg, spec=spec),
         mesh=mesh,
         in_specs=(fspecs, P(), P(), P(), P()),
         out_specs=(fspecs, _metric_specs(cfg)),
-        check_rep=False,  # psum'd outputs are replicated by construction
+        check_vma=False,  # psum'd outputs are replicated by construction
     )
     new_fields, metrics = fn(fields, jax.random.key_data(k_move),
                              jax.random.key_data(k_send), state["t"], mf)
@@ -1138,13 +1140,13 @@ def step_sharded_batch(state, cfg, spec: ShardSpec, mesh: Mesh, mfs):
     key, k_move, k_send = ks[:, 0], ks[:, 1], ks[:, 2]
     fspecs = _field_specs(spec)
     fields = {k: state[k] for k in fspecs}
-    fn = shard_map(
+    fn = jax.shard_map(
         jax.vmap(partial(_shard_step, cfg=cfg, spec=spec),
                  in_axes=(0, 0, 0, 0, 0)),
         mesh=mesh,
         in_specs=(_batch_field_specs(spec), P(), P(), P(), P()),
         out_specs=(_batch_field_specs(spec), _metric_specs(cfg)),
-        check_rep=False,
+        check_vma=False,
     )
     new_fields, metrics = fn(fields, jax.random.key_data(k_move),
                              jax.random.key_data(k_send), state["t"], mfs)
@@ -1244,9 +1246,9 @@ def _compiled_depart_sharded(key_cfg):
     spec = make_shard_spec(key_cfg)
     mesh = make_mesh(spec)
     fspecs = _field_specs(spec)
-    fn = shard_map(partial(_shard_depart, spec=spec), mesh=mesh,
-                   in_specs=(fspecs, P()), out_specs=(fspecs, P()),
-                   check_rep=False)
+    fn = jax.shard_map(partial(_shard_depart, spec=spec), mesh=mesh,
+                       in_specs=(fspecs, P()), out_specs=(fspecs, P()),
+                       check_vma=False)
     return jax.jit(fn), spec
 
 
@@ -1255,10 +1257,10 @@ def _compiled_arrive_sharded(key_cfg):
     spec = make_shard_spec(key_cfg)
     mesh = make_mesh(spec)
     fspecs = _field_specs(spec)
-    fn = shard_map(partial(_shard_arrive, cfg=key_cfg, spec=spec),
-                   mesh=mesh,
-                   in_specs=(fspecs, P(), P(), P(), P(), P(), P()),
-                   out_specs=(fspecs, P()), check_rep=False)
+    fn = jax.shard_map(partial(_shard_arrive, cfg=key_cfg, spec=spec),
+                       mesh=mesh,
+                       in_specs=(fspecs, P(), P(), P(), P(), P(), P()),
+                       out_specs=(fspecs, P()), check_vma=False)
     return jax.jit(fn), spec
 
 

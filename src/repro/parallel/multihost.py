@@ -115,12 +115,11 @@ def _probe_collectives(mesh) -> bool:
     jaxlib), instead of letting the first real scan die mid-flight."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     try:
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda x: jax.lax.psum(x, "lp"), mesh=mesh,
-            in_specs=P(), out_specs=P(), check_rep=False))
+            in_specs=P(), out_specs=P(), check_vma=False))
         jax.block_until_ready(fn(jnp.float32(1.0)))
         return True
     except Exception as e:  # jaxlib raises XlaRuntimeError

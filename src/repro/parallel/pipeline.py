@@ -17,7 +17,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -68,5 +67,5 @@ def gpipe(stage_fn: Callable, mesh, axis: str, n_micro: int):
         return outs
 
     in_specs = (P(axis), P())
-    return shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                     check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(), check_vma=False)

@@ -8,6 +8,7 @@ exp6_scenarios.run_cell, whose physics is covered by
 tests/test_workloads.py and the nightly fleet run.
 """
 import json
+import os
 
 import pytest
 
@@ -55,6 +56,24 @@ def test_stub_executors_refuse_loudly():
     with pytest.raises(NotImplementedError):
         fleet.Executor().run([])
     assert set(fleet.EXECUTORS) == {"local", "container", "k8s"}
+
+
+def test_local_executor_refuses_where_this_process_holds_the_chips(
+        monkeypatch):
+    from benchmarks import common
+    monkeypatch.setattr(common, "on_accelerator", lambda: True)
+    with pytest.raises(RuntimeError, match="holds the accelerator"):
+        fleet.LocalExecutor(workers=1).run(
+            fleet.build_matrix("quick", 1)[:1])
+
+
+def test_run_child_section_stays_in_process_on_an_accelerator(monkeypatch):
+    from benchmarks import common
+    monkeypatch.setattr(common, "on_accelerator", lambda: True)
+    code = 'import json, os\nprint("RESULT " + json.dumps(os.getpid()))\n'
+    assert common.run_child(code, 1) == os.getpid()
+    with pytest.raises(RuntimeError, match="needs 64 devices"):
+        common.run_child(code, 64)
 
 
 def _fake_row(scenario, gain):
