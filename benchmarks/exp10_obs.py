@@ -1,4 +1,4 @@
-"""Experiment 10 (beyond-paper): telemetry overhead + trace export.
+"""Experiment 10 (beyond-paper): telemetry overhead.
 
 Measures the ISSUE-9 observability acceptance bars:
 
@@ -13,13 +13,8 @@ Measures the ISSUE-9 observability acceptance bars:
    obs-off run on the same seed (per-step series compared exactly), and
    the drained ledger must reproduce the series it mirrors. Asserted
    here so the nightly gate re-proves it at bench scale, not just at
-   test scale (tests/test_obs.py).
-3. **Trace export** — a 2-device section (benchmarks.common.run_child)
-   traces a short sharded run phase-by-phase and writes a
-   Chrome-trace/Perfetto JSON (results/exp10_trace.json, CI artifact);
-   the caller validates the timeline structure (per-device rows,
-   step-phase spans). The events JSONL from the overhead run lands next
-   to it (results/exp10_events.jsonl).
+   test scale (tests/test_obs.py). The events JSONL from the overhead
+   run lands in results/exp10_events.jsonl (CI artifact).
 
 Results land in BENCH_obs.json; `obs.overhead_ratio` is tracked by
 benchmarks/compare.py against BENCH_baseline/ (a time/time ratio —
@@ -41,7 +36,7 @@ if __package__ in (None, ""):  # script invocation: python benchmarks/...
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from benchmarks.common import engine_cfg, run_child  # noqa: E402
+from benchmarks.common import engine_cfg  # noqa: E402
 from repro.compile_cache import use_compile_cache  # noqa: E402
 from repro.core.service import Engine  # noqa: E402
 from repro.obs import ObsConfig, Telemetry, runtime  # noqa: E402
@@ -49,14 +44,11 @@ from repro.obs import ObsConfig, Telemetry, runtime  # noqa: E402
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 OUT = os.path.join(REPO, "BENCH_obs.json")
 RESULTS_DIR = os.path.join(REPO, "results")
-TRACE_OUT = os.path.join(RESULTS_DIR, "exp10_trace.json")
 EVENTS_OUT = os.path.join(RESULTS_DIR, "exp10_events.jsonl")
 
 OVERHEAD_BOUND = 1.10  # ISSUE-9 bar: < 10% wall overhead at drain_every=10
 DRAIN_EVERY = 10
 TIME_REPS = {"quick": 3, "full": 5}
-TRACE_DEVS = 2
-TRACE_STEPS = 6
 
 SERIES_KEYS = ("lcr", "local_msgs", "remote_msgs", "migrations",
                "heu_evals")
@@ -128,62 +120,8 @@ def overhead_section(scale: str):
     }
 
 
-# 2-device section (run_child protocol): trace a short sharded run phase-by-
-# phase and save the Perfetto JSON; RESULT carries the phase summary.
-_TRACE_CODE = """
-import dataclasses, json
-from benchmarks.common import engine_cfg
-from repro.obs import trace_run
-
-cfg = dataclasses.replace(engine_cfg("quick"), timesteps={steps},
-                          sharding="lp_device", n_devices={n_dev})
-rec = trace_run(cfg, seed=0)
-rec.save({out!r})
-print("RESULT " + json.dumps({{
-    "n_devices": {n_dev}, "steps": {steps},
-    "spans": sum(1 for e in rec.events if e.get("ph") == "X"),
-    "phase_summary": rec.phase_summary(),
-}}))
-"""
-
-
-def trace_section():
-    """Sharded step-phase timeline on TRACE_DEVS devices (run_child);
-    the parent re-opens the saved JSON and validates the Perfetto
-    structure it promises CI consumers."""
-    os.makedirs(RESULTS_DIR, exist_ok=True)
-    row = run_child(
-        _TRACE_CODE.format(steps=TRACE_STEPS, n_dev=TRACE_DEVS,
-                           out=TRACE_OUT),
-        TRACE_DEVS)
-    with open(TRACE_OUT, encoding="utf-8") as fh:
-        doc = json.load(fh)
-    spans = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
-    assert spans, "trace exported no phase spans"
-    assert {e["tid"] for e in spans} == set(range(TRACE_DEVS)), \
-        "trace missing per-device timeline rows"
-    names = {e["name"] for e in spans}
-    assert {"migrate", "mobility", "halo_exchange", "proximity",
-            "finalize"} <= names, f"phases missing from trace: {names}"
-    phases = row["phase_summary"]
-    print(f"[exp10] trace: {row['spans']} spans over {TRACE_STEPS} steps "
-          f"x {TRACE_DEVS} devices -> {TRACE_OUT}")
-    for name, st in sorted(phases.items(),
-                           key=lambda kv: -kv[1]["total"]):
-        print(f"[exp10]   {name:14s} mean {st['mean'] * 1e3:7.2f}ms "
-              f"total {st['total']:.3f}s (n={st['n']})")
-    return {
-        "n_devices": TRACE_DEVS, "steps": TRACE_STEPS,
-        "spans": row["spans"], "trace_path": os.path.relpath(
-            TRACE_OUT, REPO),
-        "phase_summary": {k: {kk: round(vv, 6) for kk, vv in st.items()}
-                          for k, st in phases.items()},
-    }
-
-
 def main(scale: str = "quick"):
     overhead = overhead_section(scale)
-    trace = trace_section()
 
     result = {
         "experiment": "exp10_obs",
@@ -191,7 +129,6 @@ def main(scale: str = "quick"):
                        n_se=engine_cfg("quick").abm.n_se,
                        drain_every=DRAIN_EVERY),
         "obs": overhead,
-        "trace": trace,
         "gate": {
             "overhead_ratio": {"value": overhead["overhead_ratio"],
                                "bound": OVERHEAD_BOUND, "dir": "lower"},

@@ -3,7 +3,6 @@
     PYTHONPATH=src python -m benchmarks.run [--scale quick|mid|paper]
                                             [--only exp1,exp2,...]
                                             [--replicas R]
-                                            [--trace [N_STEPS]]
 
 Experiments (see DESIGN.md §Per-experiment index):
     exp1      Fig. 5  — LCR & migrations vs. speed x MF
@@ -19,18 +18,10 @@ Experiments (see DESIGN.md §Per-experiment index):
               (BENCH_replicas)
     exp9      beyond-paper: resident engine service — open-world churn
               throughput + request multiplexing (BENCH_service)
-    exp10     beyond-paper: telemetry overhead + step-phase trace
-              export (BENCH_obs)
+    exp10     beyond-paper: telemetry overhead (BENCH_obs)
     tables23  Tables 2-3 + Figs. 8-9 — ΔWCT via the calibrated cost model
     gaiamoe   beyond-paper: adaptive MoE expert placement traffic
     roofline  assemble the §Roofline table from results/dryrun
-
-`--trace` skips the benchmark sweep and exports step-phase trace
-timelines instead (repro.obs.trace): one Chrome-trace/Perfetto JSON per
-execution layer — results/trace_oracle.json and, on a >= 2-device
-topology (forced automatically on CPU), results/trace_lp_device.json —
-openable directly at https://ui.perfetto.dev or chrome://tracing. The
-optional argument is the number of steps to trace (default 8).
 
 `--replicas` sets the replica count for the statistical experiments
 (exp1/2/3/6/7, tables23 — and the batch size of exp8); the default is 5
@@ -44,51 +35,9 @@ The dry-run campaign itself (benchmarks/dryrun_all.py) is run separately
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import time
 import traceback
-
-
-def trace_main(n_steps: int) -> int:
-    """Export step-phase Perfetto timelines for both execution layers
-    (the --trace mode). Must run before any bench import pulls in jax:
-    the sharded trace needs a multi-device topology, which on CPU is an
-    env var that only counts before the first jax import."""
-    if "host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                                   " --xla_force_host_platform_device_count=4")
-    import dataclasses
-
-    import jax
-
-    from benchmarks.common import engine_cfg
-    from repro.compile_cache import use_compile_cache
-    from repro.obs import trace_run
-
-    print(f"[trace] compile cache: {use_compile_cache()}")
-
-    results = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "results")
-    os.makedirs(results, exist_ok=True)
-    base = dataclasses.replace(engine_cfg("quick"), timesteps=n_steps)
-    layers = [("oracle", base)]
-    n_dev = jax.device_count()
-    if n_dev >= 2:
-        layers.append(("lp_device", dataclasses.replace(
-            base, sharding="lp_device", n_devices=min(n_dev, 4))))
-    else:
-        print("[trace] single-device topology: skipping the lp_device "
-              "timeline")
-    for name, cfg in layers:
-        rec = trace_run(cfg, seed=0)
-        path = rec.save(os.path.join(results, f"trace_{name}.json"))
-        phases = rec.phase_summary()
-        total = sum(st["total"] for st in phases.values())
-        print(f"[trace] {name}: {n_steps} steps, "
-              f"{len(phases)} phases, {total:.3f}s total -> {path}")
-    print("[trace] open at https://ui.perfetto.dev or chrome://tracing")
-    return 0
 
 
 def main() -> int:
@@ -99,14 +48,7 @@ def main() -> int:
     ap.add_argument("--replicas", type=int, default=None,
                     help="replica count for the statistical experiments "
                          "(default: 5 quick, 10 mid/paper)")
-    ap.add_argument("--trace", nargs="?", type=int, const=8, default=None,
-                    metavar="N_STEPS",
-                    help="export step-phase Perfetto timelines instead of "
-                         "running benchmarks (default 8 steps)")
     args = ap.parse_args()
-
-    if args.trace is not None:
-        return trace_main(args.trace)
 
     from repro.compile_cache import use_compile_cache
     print(f"[run] compile cache: {use_compile_cache()}")

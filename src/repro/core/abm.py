@@ -624,6 +624,22 @@ def interaction_counts(pos, lp, sender_mask, cfg: ABMConfig):
     return interaction_counts_overflow(pos, lp, sender_mask, cfg)[0]
 
 
+def walk_slots(cfg: ABMConfig) -> int:
+    """Candidate slots one call of `interaction_counts_overflow` tests,
+    senders or not: the grid walk's padded rows x 9 x capacity, the
+    Pallas grid kernel's N x 9 x capacity, N^2 for the dense sweeps. The
+    useful share of the walk is the in-range sender pairs over this."""
+    backend = cfg.resolved_backend()
+    spec = cfg.grid_spec() if backend in ("grid", "pallas_grid") else None
+    n = cfg.n_se
+    if spec is None:
+        return n * n
+    if backend == "grid":
+        return neighbors.grid_walk(
+            n, spec.capacity, neighbors.chunk_entries(cfg.mem_budget_mb))[2]
+    return n * 9 * spec.capacity
+
+
 # ---------------------------------------------------------------------------
 # Epidemic/gossip diffusion workload (ABMConfig.workload == "epidemic")
 # ---------------------------------------------------------------------------

@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.core import balance as bal
 from repro.core import partition as part
+from repro.core import abm as _abm
 from repro.core.abm import (ABMConfig, check_trace_horizon,
                             epidemic_draws, epidemic_exposure_overflow,
                             epidemic_row_update, epidemic_send_prob,
@@ -226,10 +227,11 @@ def step_phases(cfg: EngineConfig):
     Each phase is a pure function over a growing "phase context" dict
     `px` (state under "st", plus the intermediates earlier phases
     added). `step` composes the phases fused — same ops, same order, so
-    the compiled scan is the historical program — while the trace
-    executor (repro.obs.trace) jits each phase separately to time it
-    and emit per-phase timeline spans. Inactive phases (repartition
-    with repartition_every=0, heuristic with gaia_on=False) are simply
+    the compiled scan is the historical program — each under a
+    `step.<phase>` named scope, which the compiled program keeps as the
+    `op_name` of its ops: a profiler trace attributes device time to
+    phases by it. Inactive phases (repartition with
+    repartition_every=0, heuristic with gaia_on=False) are simply
     absent from the list."""
     n, L = cfg.abm.n_se, cfg.abm.n_lp
     ow = cfg.open_world
@@ -449,8 +451,10 @@ def step(state, cfg: EngineConfig, mf=None):
     column and `valid` keeps them out of the grid), never evaluate, and
     never migrate.
 
-    The body is the fused composition of `step_phases` (the named-scope
-    annotations show up in jax.profiler timelines; they add no ops)."""
+    The body is the fused composition of `step_phases`, each phase
+    under its `step.<phase>` named scope (the profiler trace's phase
+    attribution; scopes add no ops). Host spans belong to the caller
+    (`core/service.Engine.step`), never to this traced body."""
     px = {"st": state, "mf": mf}
     for name, fn in step_phases(cfg):
         with jax.named_scope(f"step.{name}"):
@@ -513,26 +517,39 @@ def oracle_depart(state, ids):
     return _clear_slot_history(st, tgt)
 
 
-def series_counters(series) -> dict:
+class HostReads:
+    """`np.asarray` that counts its calls: the device-to-host transfers
+    one counter read makes (the `fetches` of its readback span)."""
+
+    def __init__(self):
+        self.count = 0
+
+    def __call__(self, x):
+        self.count += 1
+        return np.asarray(x)
+
+
+def series_counters(series, read=np.asarray) -> dict:
     """Aggregate a per-step metrics series into run counters — the one
     place the counter/series key contract lives (the sharded runner
     layers its extra metrics on top). Matrix-valued series (the per-pair
     flow counters) aggregate to nested lists in int64 so long runs
-    cannot wrap int32."""
-    counters = {k: float(series[k].sum()) for k in
+    cannot wrap int32. Every value reaches the host through `read`, one
+    transfer per call."""
+    counters = {k: float(read(series[k].sum())) for k in
                 ("local_msgs", "remote_msgs", "migrations", "heu_evals")}
-    counters["mean_lcr"] = float(series["lcr"].mean())
+    counters["mean_lcr"] = float(read(series["lcr"].mean()))
     if "pop" in series:
-        counters["mean_pop"] = float(series["pop"].mean())
+        counters["mean_pop"] = float(read(series["pop"].mean()))
     if "infected" in series:
-        counters["mean_infected"] = float(series["infected"].mean())
-        counters["final_infected"] = float(series["infected"][-1])
+        counters["mean_infected"] = float(read(series["infected"].mean()))
+        counters["final_infected"] = float(read(series["infected"][-1]))
     for k in ("grid_overflow", "repartitions"):
         if k in series:
-            counters[k] = float(series[k].sum())
+            counters[k] = float(read(series[k].sum()))
     for k in ("lp_flows", "mig_flows"):
         if k in series:
-            counters[k] = np.asarray(series[k]).sum(
+            counters[k] = read(series[k]).sum(
                 axis=0, dtype=np.int64).tolist()
     return counters
 
@@ -657,6 +674,44 @@ def _compiled_window(cfg: EngineConfig, n_steps: int):
     return _compiled_window_cached(window_key_cfg(cfg), n_steps)
 
 
+def walk_slots(cfg: EngineConfig) -> int:
+    """Candidate slots the proximity walk of one step of one replica
+    visits, on either execution layer (the sharded layer: the sum of
+    every device's own walk) — the `walk_slots` of a window's dispatch
+    span."""
+    if cfg.sharding == "lp_device":
+        from repro.parallel import lp_shard
+        return lp_shard.walk_slots(cfg)
+    return _abm.walk_slots(cfg.abm)
+
+
+def _dispatch_window(state, cfg: EngineConfig, n_steps: int, mf=None):
+    """Enqueue one n_steps window on either execution layer; returns
+    (state, per-step series) without waiting for the device (with
+    telemetry on, after the ledger's tail is flushed)."""
+    _trace_guard(state, cfg, n_steps)
+    if cfg.sharding == "lp_device":
+        from repro.parallel import lp_shard
+        return lp_shard._scan_sharded(state, cfg, n_steps, mf=mf)
+
+    mf_val = jnp.float32(cfg.heuristic.mf if mf is None else mf)
+    if cfg.obs.enabled:
+        t0 = int(state["t"])
+        state, ring, series = _compiled_window(cfg, n_steps)(state, mf_val)
+        obs_runtime.flush_tail(ring, t0, t0 + n_steps)
+        return state, series
+    return _compiled_window(cfg, n_steps)(state, mf_val)
+
+
+def window_counters(series, cfg: EngineConfig, read=np.asarray) -> dict:
+    """The counter read of one window's series, on either execution
+    layer (see `series_counters`)."""
+    if cfg.sharding == "lp_device":
+        from repro.parallel import lp_shard
+        return lp_shard._series_counters(series, read)
+    return series_counters(series, read)
+
+
 def _run_window(state, cfg: EngineConfig, n_steps: int, mf=None):
     """Advance an existing state by n_steps; returns (state, counters).
 
@@ -665,19 +720,8 @@ def _run_window(state, cfg: EngineConfig, n_steps: int, mf=None):
     dynamic argument: no recompilation between windows). Sharded states
     (from a sharded init_engine) advance through the sharded step and
     stay slot-major."""
-    _trace_guard(state, cfg, n_steps)
-    if cfg.sharding == "lp_device":
-        from repro.parallel import lp_shard
-        return lp_shard.run_window_sharded(state, cfg, n_steps, mf=mf)
-
-    mf_val = jnp.float32(cfg.heuristic.mf if mf is None else mf)
-    if cfg.obs.enabled:
-        t0 = int(state["t"])
-        state, ring, series = _compiled_window(cfg, n_steps)(state, mf_val)
-        obs_runtime.flush_tail(ring, t0, t0 + n_steps)
-    else:
-        state, series = _compiled_window(cfg, n_steps)(state, mf_val)
-    return state, series_counters(series)
+    state, series = _dispatch_window(state, cfg, n_steps, mf=mf)
+    return state, window_counters(series, cfg)
 
 
 def _run(key, cfg: EngineConfig):
@@ -771,6 +815,26 @@ def _compiled_batch(cfg: EngineConfig, n_steps: int):
     return _compiled_batch_cached(window_key_cfg(strip_obs(cfg)), n_steps)
 
 
+def _dispatch_window_batch(states, cfg: EngineConfig, n_steps: int,
+                           mf=None):
+    """Enqueue one batched n_steps window of R stacked replicas on
+    either execution layer; returns (states, (T, R, ...) series)
+    without waiting for the device."""
+    _trace_guard(states, cfg, n_steps)
+    if cfg.sharding == "lp_device":
+        from repro.parallel import lp_shard
+        return lp_shard._scan_batch_sharded(states, cfg, n_steps, mf=mf)
+    n_rep = states["t"].shape[0]
+    return _compiled_batch(cfg, n_steps)(states, _mf_vector(cfg, mf, n_rep))
+
+
+def batch_counters(series, cfg: EngineConfig, read=np.asarray) -> list:
+    """Per-replica counter reads of one batched window's series."""
+    n_rep = series["lcr"].shape[1]
+    return [window_counters(replica_series(series, r), cfg, read)
+            for r in range(n_rep)]
+
+
 def _run_window_batch(states, cfg: EngineConfig, n_steps: int, mf=None):
     """Advance R stacked replica states by n_steps in one batched scan.
 
@@ -778,16 +842,8 @@ def _run_window_batch(states, cfg: EngineConfig, n_steps: int, mf=None):
     §5.5 tuner descends each replica's MF independently, so MF rides as
     a per-replica dynamic argument of the one compiled scan. Returns
     (states, [per-replica counters])."""
-    _trace_guard(states, cfg, n_steps)
-    if cfg.sharding == "lp_device":
-        from repro.parallel import lp_shard
-        return lp_shard.run_window_batch_sharded(states, cfg, n_steps,
-                                                 mf=mf)
-    n_rep = states["t"].shape[0]
-    states, series = _compiled_batch(cfg, n_steps)(
-        states, _mf_vector(cfg, mf, n_rep))
-    return states, [series_counters(replica_series(series, r))
-                    for r in range(n_rep)]
+    states, series = _dispatch_window_batch(states, cfg, n_steps, mf=mf)
+    return states, batch_counters(series, cfg)
 
 
 def _run_batch(cfg: EngineConfig, seeds):

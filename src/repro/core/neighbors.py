@@ -53,6 +53,10 @@ _NEIGH_OFFSETS = [(di, dj) for di in (-1, 0, 1) for dj in (-1, 0, 1)]
 #: auto-chunking target: max candidate-matrix entries resident at once
 _CHUNK_BUDGET = 1 << 22
 
+#: rows per chunk of the sharded dense sweep (`rows_dense_counts`), which
+#: pads its rows to a whole number of chunks
+DENSE_CHUNK = 2048
+
 #: resident bytes per (row, candidate-slot) entry of one chunked sweep:
 #: the ~5 live (chunk, capacity) i32/f32 intermediates (indices, validity,
 #: gathered positions, distances, mask) — what `chunk_entries` divides a
@@ -312,6 +316,21 @@ def rows_counts_chunked(pos, lp, n_lp: int, area: float, rng: float,
     return out.reshape(n_chunks * chunk, n_lp)[:r]
 
 
+def grid_walk(rows: int, capacity: int, budget_entries: int = 0) -> tuple:
+    """The chunk rule of `rows_grid_counts` over `rows` rows: (chunk,
+    n_chunks, slots). One chunk holds every row when the budget allows;
+    otherwise rows are padded to `n_chunks` chunks of `chunk`. `slots`
+    is the number of candidate slots the walk visits, padded rows
+    included: every row tests 9 neighbour cells of `capacity` slots."""
+    budget = budget_entries if budget_entries > 0 else _CHUNK_BUDGET
+    chunk = max(1, budget // max(capacity, 1))
+    if rows <= chunk:
+        chunk, n_chunks = rows, 1
+    else:
+        n_chunks = -(-rows // chunk)
+    return chunk, n_chunks, chunk * n_chunks * len(_NEIGH_OFFSETS) * capacity
+
+
 def rows_grid_counts(pos, lp, n_lp: int, area: float, rng: float,
                      spec: GridSpec, grid, row_pos, row_idx, row_sender,
                      budget_entries: int = 0):
@@ -364,11 +383,9 @@ def rows_grid_counts(pos, lp, n_lp: int, area: float, rng: float,
         return acc
 
     r = row_pos.shape[0]
-    budget = budget_entries if budget_entries > 0 else _CHUNK_BUDGET
-    chunk = max(1, budget // max(cap, 1))
-    if r <= chunk:
+    chunk, n_chunks, _ = grid_walk(r, cap, budget_entries)
+    if n_chunks == 1:
         return counts_for(row_pos, row_idx, row_sender, row_cell)
-    n_chunks = -(-r // chunk)
     pad = n_chunks * chunk - r
     rp = jnp.pad(row_pos, ((0, pad), (0, 0)))
     ri = jnp.pad(row_idx, (0, pad), constant_values=-1)
@@ -545,7 +562,8 @@ def cell_block_mean(pos, vec, spec: GridSpec, area: float, valid=None):
 
 
 def rows_dense_counts(pos, lp, n_lp: int, area: float, rng: float,
-                      row_pos, row_idx, row_sender, chunk: int = 2048):
+                      row_pos, row_idx, row_sender,
+                      chunk: int = DENSE_CHUNK):
     """Dense-sweep counts for a row subset against the global reference
     arrays — the sharded engine's fallback when the world is too small to
     tessellate. Reference entries with lp < 0 (empty shard slots) one-hot

@@ -47,6 +47,7 @@ from repro.core.engine import EngineConfig
 from repro.core.stats import merge_counters
 from repro.obs import runtime as obs_runtime
 from repro.obs.ledger import Telemetry
+from repro.obs.trace import Spans
 
 
 def _pad_pow2(b: int) -> int:
@@ -91,6 +92,9 @@ class Engine:
         # windowed call (repro.obs.runtime routing).
         self.telemetry = (Telemetry(cfg, sinks=obs_sinks)
                           if cfg.obs.enabled else None)
+        # program spans of every call (repro.obs.trace): one call id per
+        # call, shared by its stages
+        self._spans = Spans()
 
     # -- lifecycle -------------------------------------------------------
 
@@ -105,6 +109,10 @@ class Engine:
                                            self.cfg)
             self._batched = False
         self._parts, self._weights, self._steps = [], [], 0
+        # the proximity walk's candidate slots per step of all replicas
+        # (a step's `walk_slots`)
+        self._walk = _eng.walk_slots(self.cfg) * (
+            self.state["t"].shape[0] if self._batched else 1)
         live = self.cfg.initial_live()
         self._live = set(range(live))
         self._free = list(range(self.cfg.abm.n_se - 1, live - 1, -1))
@@ -142,16 +150,32 @@ class Engine:
         per-replica dicts when batched) and accumulates them into
         `metrics()`. `mf` overrides the Migration Factor for the window
         (per-replica vector allowed when batched) — the §5.5 tuners'
-        contract, unchanged."""
+        contract, unchanged.
+
+        Spans: `gaia.step` (n steps) holds `dispatch` (the window
+        program enqueued; `walk_slots` per step), `wait` (until the
+        device is done) and `readback` (the counter read; `fetches`
+        device-to-host transfers)."""
         self._require_state()
         if self.telemetry is not None:
             obs_runtime.set_current(self.telemetry)
         if self._batched:
-            self.state, counters = _eng._run_window_batch(
-                self.state, self.cfg, n, mf=mf)
+            dispatch = _eng._dispatch_window_batch
+            read_counters = _eng.batch_counters
         else:
-            self.state, counters = _eng._run_window(
-                self.state, self.cfg, n, mf=mf)
+            dispatch = _eng._dispatch_window
+            read_counters = _eng.window_counters
+        sp = self._spans
+        with sp.call("step", n=n):
+            with sp.child("dispatch", walk_slots=self._walk):
+                self.state, series = dispatch(self.state, self.cfg, n,
+                                              mf=mf)
+            with sp.child("wait"):
+                jax.block_until_ready(series)
+            with sp.child("readback") as span:
+                read = _eng.HostReads()
+                counters = read_counters(series, self.cfg, read)
+                span.set_metadata(fetches=read.count)
         self._parts.append(counters)
         self._weights.append(n)
         self._steps += n
@@ -240,18 +264,36 @@ class Engine:
         RuntimeError, state untouched, if the universe has fewer than B
         free slots; on the sharded layer a destination device without a
         free slot raises too (naming shard_capacity), with the admitted
-        prefix of the batch applied and reported."""
+        prefix of the batch applied and reported.
+
+        Spans: `gaia.arrive` (`batch`, `padded` rows) holds `prepare`
+        (padding, free-slot bookkeeping), `apply` (the jitted update)
+        and, on the sharded layer, `sync` (the admission mask read)."""
         import numpy as np
         self._require_open("arrive")
-        pos = np.asarray(rows["pos"], np.float32).reshape(-1, 2)
+        sp = self._spans
+        with sp.call("arrive") as call:
+            with sp.child("prepare"):
+                pos = np.asarray(rows["pos"], np.float32).reshape(-1, 2)
+                b = pos.shape[0]
+                if b == 0:
+                    return []
+                if b > len(self._free):
+                    raise RuntimeError(
+                        f"arrive: batch of {b} exceeds the "
+                        f"{len(self._free)} free slots of the "
+                        f"n_se={self.cfg.abm.n_se} universe; raise "
+                        "abm.n_se (the slot universe) or depart SEs first")
+                ids, pad_ids, prows = self._pad_arrivals(rows, pos)
+                call.set_metadata(batch=b, padded=len(pad_ids))
+            self._admit(ids, pad_ids, prows)
+        return ids
+
+    def _pad_arrivals(self, rows, pos):
+        """Take free slots for the arrivals at `pos` and pad the batch
+        to a power of two: (ids, padded ids, padded rows)."""
+        import numpy as np
         b = pos.shape[0]
-        if b == 0:
-            return []
-        if b > len(self._free):
-            raise RuntimeError(
-                f"arrive: batch of {b} exceeds the {len(self._free)} "
-                f"free slots of the n_se={self.cfg.abm.n_se} universe; "
-                "raise abm.n_se (the slot universe) or depart SEs first")
         abm = self.cfg.abm
         if "lp" in rows:
             lps = np.asarray(rows["lp"], np.int32).reshape(-1)
@@ -276,11 +318,21 @@ class Engine:
             buf = np.zeros((bp,), np.int32)
             buf[:b] = np.asarray(rows["epi"], np.int32).reshape(-1)
             prows["epi"] = buf
+        return ids, pad_ids, prows
+
+    def _admit(self, ids, pad_ids, prows) -> None:
+        """Write padded arrivals into their slots; the sharded layer
+        reads back which were admitted and raises for the refused."""
+        import numpy as np
+        sp = self._spans
+        b = len(ids)
         if self.cfg.sharding == "lp_device":
             from repro.parallel import lp_shard
-            self.state, adm = lp_shard.arrive_sharded(
-                self.state, self.cfg, pad_ids, prows)
-            adm = np.asarray(adm)[:b]
+            with sp.child("apply"):
+                self.state, adm = lp_shard.arrive_sharded(
+                    self.state, self.cfg, pad_ids, prows)
+            with sp.child("sync"):
+                adm = np.asarray(adm)[:b]
             if not adm.all():
                 refused = [i for i, ok in zip(ids, adm) if not ok]
                 self._free.extend(reversed(refused))
@@ -292,40 +344,51 @@ class Engine:
                     "EngineConfig.shard_capacity (admitted: "
                     f"{len(admitted)} rows, already applied)")
         else:
-            self.state = _jit_oracle_arrive(self.state, pad_ids, prows)
+            with sp.child("apply"):
+                self.state = _jit_oracle_arrive(self.state, pad_ids, prows)
         self._live.update(ids)
         if self.telemetry is not None:
             self.telemetry.emit("arrive", self._steps, count=b,
                                 population=len(self._live))
-        return ids
 
     def depart(self, ids) -> None:
         """Remove the SEs `ids` (an O(batch) in-device update). Their
         slots return to the free pool. Raises KeyError, state untouched,
-        if any id is not live."""
+        if any id is not live.
+
+        Spans: `gaia.depart` (`batch`, `padded` ids) holds `prepare`,
+        `apply` and, on the sharded layer, `sync`, as `arrive` does."""
         import numpy as np
         self._require_open("depart")
-        ids = [int(i) for i in ids]
-        if not ids:
-            return
-        missing = [i for i in ids if i not in self._live]
-        if missing or len(set(ids)) != len(ids):
-            raise KeyError(
-                f"depart: not live (or duplicated in batch): "
-                f"{sorted(set(missing or ids))[:8]}")
-        b = len(ids)
-        pad_ids = np.full((_pad_pow2(b),), -1, np.int32)
-        pad_ids[:b] = ids
-        if self.cfg.sharding == "lp_device":
-            from repro.parallel import lp_shard
-            self.state, found = lp_shard.depart_sharded(
-                self.state, self.cfg, pad_ids)
-            if not np.asarray(found)[:b].all():
-                raise RuntimeError(
-                    "depart: live-set bookkeeping and device state "
-                    "disagree — some ids were not found in any slot")
-        else:
-            self.state = _jit_oracle_depart(self.state, pad_ids)
+        sp = self._spans
+        with sp.call("depart") as call:
+            with sp.child("prepare"):
+                ids = [int(i) for i in ids]
+                if not ids:
+                    return
+                missing = [i for i in ids if i not in self._live]
+                if missing or len(set(ids)) != len(ids):
+                    raise KeyError(
+                        f"depart: not live (or duplicated in batch): "
+                        f"{sorted(set(missing or ids))[:8]}")
+                b = len(ids)
+                pad_ids = np.full((_pad_pow2(b),), -1, np.int32)
+                pad_ids[:b] = ids
+                call.set_metadata(batch=b, padded=len(pad_ids))
+            if self.cfg.sharding == "lp_device":
+                from repro.parallel import lp_shard
+                with sp.child("apply"):
+                    self.state, found = lp_shard.depart_sharded(
+                        self.state, self.cfg, pad_ids)
+                with sp.child("sync"):
+                    found = np.asarray(found)[:b]
+                if not found.all():
+                    raise RuntimeError(
+                        "depart: live-set bookkeeping and device state "
+                        "disagree — some ids were not found in any slot")
+            else:
+                with sp.child("apply"):
+                    self.state = _jit_oracle_depart(self.state, pad_ids)
         self._live.difference_update(ids)
         self._free.extend(reversed(ids))
         if self.telemetry is not None:
@@ -350,7 +413,11 @@ class Engine:
         """{id: sorted list of live SE ids within interaction_range} —
         served from device state via the CSR cell list (dense fallback
         when the world is too small to tessellate). Raises KeyError for
-        ids that are not live."""
+        ids that are not live.
+
+        Spans: `gaia.query_neighbors` (`ids`) holds `issue` (the eager
+        programs enqueued: `grid`, the cell list, and `walk`, the
+        candidate test), `wait` and `readback` (transfer, dict, sort)."""
         self._single("query_neighbors")
         ids = [int(i) for i in ids]
         missing = [i for i in ids if i not in self._live]
@@ -359,65 +426,100 @@ class Engine:
         if not ids:
             return {}
         abm = self.cfg.abm
-        pos, lp, ext, valid = self._universe()
-        q = jnp.asarray(ids, jnp.int32)
-        if self.cfg.sharding == "lp_device":
-            rows = jnp.argmax(ext[None, :] == q[:, None], axis=1)
-        else:
-            rows = q
-        rows = rows.astype(jnp.int32)
-        qpos = pos[rows]
-        spec = abm.grid_spec() if abm.resolved_backend() in (
-            "grid", "pallas_grid") else None
-        if spec is not None:
-            grid = neighbors.build_grid(pos, spec, valid=valid,
-                                        with_table=False)
-            cols = neighbors.rows_grid_neighbor_ids(
-                pos, abm.area, abm.interaction_range, spec, grid, qpos,
-                rows)
-        else:
-            d2 = neighbors.toroidal_d2(qpos[:, None, :], pos[None, :, :],
-                                       abm.area)
-            r2 = abm.interaction_range * abm.interaction_range
-            j = jnp.arange(pos.shape[0], dtype=jnp.int32)
-            ok = valid[None, :] & (d2 <= r2) & (j[None, :] != rows[:, None])
-            cols = jnp.where(ok, j[None, :], -1)
-        nbr = jnp.where(cols >= 0, ext[jnp.clip(cols, 0, None)], -1)
-        import numpy as np
-        nbr = np.asarray(nbr)
-        return {i: sorted(int(x) for x in row if x >= 0)
-                for i, row in zip(ids, nbr)}
+        sp = self._spans
+        with sp.call("query_neighbors", ids=len(ids)):
+            with sp.child("issue"):
+                pos, lp, ext, valid = self._universe()
+                q = jnp.asarray(ids, jnp.int32)
+                if self.cfg.sharding == "lp_device":
+                    rows = jnp.argmax(ext[None, :] == q[:, None], axis=1)
+                else:
+                    rows = q
+                rows = rows.astype(jnp.int32)
+                qpos = pos[rows]
+                spec = abm.grid_spec() if abm.resolved_backend() in (
+                    "grid", "pallas_grid") else None
+                if spec is not None:
+                    with sp.child("grid"):
+                        grid = neighbors.build_grid(pos, spec, valid=valid,
+                                                    with_table=False)
+                    with sp.child("walk"):
+                        cols = neighbors.rows_grid_neighbor_ids(
+                            pos, abm.area, abm.interaction_range, spec,
+                            grid, qpos, rows)
+                else:
+                    with sp.child("walk"):
+                        d2 = neighbors.toroidal_d2(qpos[:, None, :],
+                                                   pos[None, :, :], abm.area)
+                        r2 = abm.interaction_range * abm.interaction_range
+                        j = jnp.arange(pos.shape[0], dtype=jnp.int32)
+                        ok = (valid[None, :] & (d2 <= r2)
+                              & (j[None, :] != rows[:, None]))
+                        cols = jnp.where(ok, j[None, :], -1)
+                nbr = jnp.where(cols >= 0, ext[jnp.clip(cols, 0, None)], -1)
+            with sp.child("wait"):
+                jax.block_until_ready(nbr)
+            with sp.child("readback"):
+                import numpy as np
+                nbr = np.asarray(nbr)
+                return {i: sorted(int(x) for x in row if x >= 0)
+                        for i, row in zip(ids, nbr)}
 
     def query_lcr(self) -> float:
         """Instantaneous LCR of the current placement: the fraction of
         interactions that would be LP-local if every live SE sent now —
-        the heuristics' objective read off device state, no stepping."""
+        the heuristics' objective read off device state, no stepping.
+
+        Spans: `gaia.query_lcr` holds `issue` (`counts`, the proximity
+        walk, and `flows`, the LP flow matrix and its ratio), `wait` and
+        `readback`."""
         self._single("query_lcr")
         abm = self.cfg.abm
-        pos, lp, ext, valid = self._universe()
-        counts, _ = interaction_counts_overflow(pos, lp, valid, abm,
-                                                valid=valid)
-        safe_lp = jnp.clip(lp, 0, abm.n_lp - 1)
-        flows = jnp.zeros((abm.n_lp, abm.n_lp), jnp.int32).at[
-            safe_lp].add(counts)
-        total = flows.sum()
-        return float(jnp.trace(flows) / jnp.maximum(total, 1))
+        sp = self._spans
+        with sp.call("query_lcr"):
+            with sp.child("issue"):
+                pos, lp, ext, valid = self._universe()
+                with sp.child("counts"):
+                    counts, _ = interaction_counts_overflow(
+                        pos, lp, valid, abm, valid=valid)
+                with sp.child("flows"):
+                    safe_lp = jnp.clip(lp, 0, abm.n_lp - 1)
+                    flows = jnp.zeros((abm.n_lp, abm.n_lp), jnp.int32).at[
+                        safe_lp].add(counts)
+                    total = flows.sum()
+                    lcr = jnp.trace(flows) / jnp.maximum(total, 1)
+            with sp.child("wait"):
+                jax.block_until_ready(lcr)
+            with sp.child("readback"):
+                return float(lcr)
 
     def query_region(self, bbox) -> list:
         """Sorted live SE ids with position inside `bbox` = (x0, y0,
         x1, y1), inclusive and wrap-aware per axis (x0 > x1 selects the
-        interval wrapping through the torus seam)."""
+        interval wrapping through the torus seam).
+
+        Spans: `gaia.query_region` holds `issue` (the filter and the
+        selection, whose size the host learns), `wait` and `readback`."""
         self._single("query_region")
         x0, y0, x1, y1 = (float(v) for v in bbox)
-        pos, lp, ext, valid = self._universe()
 
         def axis(v, lo, hi):
             if lo <= hi:
                 return (v >= lo) & (v <= hi)
             return (v >= lo) | (v <= hi)
 
-        hit = valid & axis(pos[:, 0], x0, x1) & axis(pos[:, 1], y0, y1)
-        return sorted(int(i) for i in ext[hit])
+        sp = self._spans
+        with sp.call("query_region"):
+            with sp.child("issue"):
+                pos, lp, ext, valid = self._universe()
+                hit = valid & axis(pos[:, 0], x0, x1) & axis(pos[:, 1], y0,
+                                                             y1)
+                sel = ext[hit]
+            with sp.child("wait"):
+                jax.block_until_ready(sel)
+            with sp.child("readback"):
+                import numpy as np
+                return sorted(int(i) for i in np.asarray(sel))
 
 
 class ReplicaService:

@@ -1,6 +1,6 @@
 """Runtime telemetry for the GAIA engine (DESIGN.md §Observability).
 
-Three pillars, all off by default (`ObsConfig.enabled = False` — a
+Two pillars, off by default (`ObsConfig.enabled = False` — a
 telemetry-off config shares compiled executables with a config that
 never heard of telemetry, and telemetry-on never perturbs PRNG streams
 or results):
@@ -11,12 +11,12 @@ or results):
   memoized single-scan architecture is never broken per step;
 * **event log** (`events`): typed, step-stamped records (migration
   bursts, repartitions, overflow alarms, churn batches, tuner moves)
-  through pluggable sinks (memory / JSONL / stdout);
-* **trace timelines** (`trace`): Chrome-trace/Perfetto JSON spans of the
-  step phases per device, from a phase-by-phase trace executor.
+  through pluggable sinks (memory / JSONL / stdout).
 
 `core.service.Engine.metrics()/events()/prometheus()` is the serving
-surface; `benchmarks/run.py --trace` the profiling one.
+surface. Apart from both, always on: **program spans** (`trace`), host
+spans at each layer boundary of `Engine`'s calls, written into the JAX
+profiler's trace beside the device ops.
 """
 from repro.obs.config import ObsConfig
 from repro.obs.events import (EVENT_KINDS, Event, EventLog, JsonlSink,
@@ -24,11 +24,10 @@ from repro.obs.events import (EVENT_KINDS, Event, EventLog, JsonlSink,
 from repro.obs.ledger import MetricsLedger, Telemetry, ledger_keys
 from repro.obs.prom import prometheus_text
 from repro.obs import runtime
-from repro.obs.trace import TraceRecorder, trace_run, trace_steps
+from repro.obs.trace import Spans
 
 __all__ = [
     "ObsConfig", "EVENT_KINDS", "Event", "EventLog", "JsonlSink",
     "MemorySink", "StdoutSink", "MetricsLedger", "Telemetry",
-    "ledger_keys", "prometheus_text", "runtime", "TraceRecorder",
-    "trace_run", "trace_steps",
+    "ledger_keys", "prometheus_text", "runtime", "Spans",
 ]

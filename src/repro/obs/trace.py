@@ -1,185 +1,52 @@
-"""Step-phase trace timelines: Chrome-trace / Perfetto JSON export.
+"""Program spans: host intervals at the engine's layer boundaries,
+written into the JAX profiler's trace on the same clock as the device
+ops (`jax.profiler.TraceAnnotation`).
 
-The fused engine scans are opaque to wall-clock phase attribution (XLA
-fuses the whole step body), so the trace executor here runs a window
-*phase by phase*: each phase of `engine.step_phases` (oracle) or
-`lp_shard._sharded_phases` (sharded, one jit(shard_map) program per
-phase — `lp_shard.sharded_trace_phases`) is dispatched as its own jitted
-call and timed host-side with `block_until_ready`. The recorder emits
-one complete-event ("ph": "X") span per (device, phase, step) in the
-Chrome trace-event format, so `benchmarks/run.py --trace` produces a
-JSON that chrome://tracing and https://ui.perfetto.dev open directly.
-
-Phase-split execution reproduces the step semantics (the phases are the
-very functions the fused step composes) but is a *profiling* surface,
-not a bit-identity one: XLA fuses differently across the cut points, so
-traced runs are not asserted byte-equal to the fused scan, and the
-timings include per-phase dispatch overhead the fused scan amortizes
-away (DESIGN.md §Observability).
-
-This module imports the execution layers lazily (function-local): the
-engine imports `repro.obs` submodules, and `repro.obs.__init__` re-
-exports this module's entry points.
+Every span is named `gaia.<call>[.<stage>...]`, its path of open spans
+on the calling thread, so parenthood is the nesting. Each carries the
+`call=<n>` id of the call it belongs to, and counters ride as further
+arguments (the profiler keeps them as event stats). With the profiler
+off a span costs a few microseconds, so spans are always on; none sits
+inside jitted code, whose Python body runs only while tracing.
+Open a trace of a run (`jax.profiler.trace(dir)`) in Perfetto or
+TensorBoard to see them beside the device ops.
 """
 from __future__ import annotations
 
-import json
-import time
-from typing import Optional
+import contextlib
+import itertools
+
+from jax.profiler import TraceAnnotation
+
+PREFIX = "gaia."
 
 
-class TraceRecorder:
-    """Collects Chrome trace events; one timeline row (tid) per device.
+class Spans:
+    """The spans of one caller's calls (one per `core.service.Engine`):
+    `call(name)` opens a call's span under a fresh id, `child(name)` a
+    stage of the innermost open span. Both yield the annotation, whose
+    `set_metadata(**args)` adds arguments known only inside the span."""
 
-    `ts`/`dur` are microseconds relative to the recorder's creation, the
-    trace-event format's native unit.
-    """
+    def __init__(self):
+        self._ids = itertools.count(1)
+        self._path = []
+        self._call = 0
 
-    def __init__(self, n_dev: int = 1, process_name: str = "gaia-engine"):
-        self.n_dev = n_dev
-        self.events: list[dict] = []
-        self._t0 = time.perf_counter()
-        self.events.append({"ph": "M", "pid": 0, "tid": 0,
-                            "name": "process_name",
-                            "args": {"name": process_name}})
-        for d in range(n_dev):
-            self.events.append({"ph": "M", "pid": 0, "tid": d,
-                                "name": "thread_name",
-                                "args": {"name": f"device {d}"}})
+    @contextlib.contextmanager
+    def call(self, name: str, **args):
+        outer, self._call = self._call, next(self._ids)
+        try:
+            with self.child(name, **args) as span:
+                yield span
+        finally:
+            self._call = outer
 
-    def add_span(self, name: str, step: int, t_start: float, t_end: float,
-                 dev_args: Optional[list] = None) -> None:
-        """One phase span, replicated onto every device row (single-
-        process SPMD executes all devices inside one XLA program, so
-        per-device wall time is not separable — per-device *data* rides
-        in `dev_args`, one dict per device)."""
-        ts = (t_start - self._t0) * 1e6
-        dur = (t_end - t_start) * 1e6
-        for d in range(self.n_dev):
-            args = {"step": step}
-            if dev_args is not None:
-                args.update(dev_args[d])
-            self.events.append({"ph": "X", "cat": "step", "name": name,
-                                "pid": 0, "tid": d, "ts": ts, "dur": dur,
-                                "args": args})
-
-    def as_dict(self) -> dict:
-        return {"traceEvents": self.events, "displayTimeUnit": "ms"}
-
-    def save(self, path: str) -> str:
-        with open(path, "w", encoding="utf-8") as fh:
-            json.dump(self.as_dict(), fh)
-        return path
-
-    def phase_summary(self) -> dict:
-        """Per-phase wall-time stats over the recorded steps (seconds):
-        {phase: {"mean": s, "total": s, "n": spans}} — device 0's row
-        only (spans are replicated across device rows)."""
-        acc: dict[str, list[float]] = {}
-        for ev in self.events:
-            if ev.get("ph") == "X" and ev["tid"] == 0:
-                acc.setdefault(ev["name"], []).append(ev["dur"] / 1e6)
-        return {k: {"mean": sum(v) / len(v), "total": sum(v), "n": len(v)}
-                for k, v in acc.items()}
-
-
-def _dev_args(px, n_dev: int) -> list:
-    """Per-device span payload from the sharded phase context: the
-    per-device counters present at this point of the step."""
-    import numpy as np
-    out = [dict() for _ in range(n_dev)]
-    for key in ("n_valid", "halo_n"):
-        if key in px:
-            vals = np.asarray(px[key])
-            for d in range(n_dev):
-                out[d][key] = int(vals[d])
-    return out
-
-
-def trace_steps(state, cfg, n_steps: int, recorder: TraceRecorder,
-                mf=None, warmup: int = 2):
-    """Advance `state` by `warmup + n_steps` steps phase-by-phase,
-    recording one span per (device, phase, step) for the last `n_steps`
-    (the warmup steps absorb per-phase compilation — two by default,
-    because input shardings settle after the first wrapped step and
-    trigger one more specialization — so spans measure steady-state
-    execution). Returns the advanced state."""
-    if cfg.sharding == "lp_device":
-        return _trace_steps_sharded(state, cfg, n_steps, recorder, mf,
-                                    warmup)
-    return _trace_steps_oracle(state, cfg, n_steps, recorder, mf, warmup)
-
-
-def _trace_steps_oracle(state, cfg, n_steps, recorder, mf, warmup):
-    import jax
-    import jax.numpy as jnp
-    from repro.core.engine import step_phases
-
-    phases = [(name, jax.jit(fn)) for name, fn in step_phases(cfg)]
-    mf_val = jnp.float32(cfg.heuristic.mf if mf is None else mf)
-    for i in range(warmup + n_steps):
-        record = i >= warmup
-        px = {"st": state, "mf": mf_val}
-        step_no = int(state["t"])
-        for name, fn in phases:
-            t0 = time.perf_counter()
-            px = fn(px)
-            jax.block_until_ready(px)
-            if record:
-                recorder.add_span(name, step_no, t0, time.perf_counter())
-        state = px["new_state"]
-    return state
-
-
-def _trace_steps_sharded(state, cfg, n_steps, recorder, mf, warmup):
-    import jax
-    import jax.numpy as jnp
-    from repro.core.engine import window_key_cfg
-    from repro.parallel import lp_shard
-
-    key_cfg = window_key_cfg(cfg)
-    spec = lp_shard.make_shard_spec(key_cfg)
-    mesh = lp_shard.make_mesh(spec)
-    phases = lp_shard.sharded_trace_phases(key_cfg, spec, mesh)
-    fkeys = list(lp_shard._field_specs(spec))
-    mf_val = jnp.float32(cfg.heuristic.mf if mf is None else mf)
-    for i in range(warmup + n_steps):
-        record = i >= warmup
-        key, k_move, k_send = jax.random.split(state["key"], 3)
-        px = {"f": {k: state[k] for k in fkeys},
-              "k_move": jax.random.key_data(k_move),
-              "k_send": jax.random.key_data(k_send),
-              "t": state["t"], "mf": mf_val}
-        step_no = int(state["t"])
-        for name, fn in phases:
-            t0 = time.perf_counter()
-            px = fn(px)
-            jax.block_until_ready(px)
-            if record:
-                recorder.add_span(name, step_no, t0, time.perf_counter(),
-                                  dev_args=_dev_args(px, spec.n_dev))
-        state = dict(px["f"], key=key, t=state["t"] + 1)
-    return state
-
-
-def trace_run(cfg, seed: int = 0, n_steps: Optional[int] = None,
-              warmup: int = 2):
-    """Initialize an engine state for `cfg`, trace `n_steps` (default
-    cfg.timesteps) phase-by-phase, and return the populated
-    :class:`TraceRecorder`."""
-    import jax
-    from repro.core.engine import _init_engine, window_key_cfg
-
-    if n_steps is None:
-        n_steps = cfg.timesteps
-    if cfg.sharding == "lp_device":
-        from repro.parallel import lp_shard
-        spec = lp_shard.make_shard_spec(window_key_cfg(cfg))
-        state = lp_shard.init_sharded(jax.random.key(seed), cfg, spec)
-        n_dev = spec.n_dev
-    else:
-        state = _init_engine(jax.random.key(seed), cfg)
-        n_dev = 1
-    recorder = TraceRecorder(n_dev=n_dev)
-    trace_steps(state, cfg, n_steps, recorder, warmup=warmup)
-    return recorder
+    @contextlib.contextmanager
+    def child(self, name: str, **args):
+        self._path.append(name)
+        try:
+            with TraceAnnotation(PREFIX + ".".join(self._path),
+                                 call=self._call, **args) as span:
+                yield span
+        finally:
+            self._path.pop()

@@ -513,13 +513,12 @@ def _gather_row_bytes(cfg) -> int:
 
 
 def _sharded_phases(cfg, spec: ShardSpec):
-    """Ordered (name, fn, adds) phase decomposition of the per-device
-    step body. Each fn maps a phase-context dict `px` (per-SE fields
-    under "f", plus intermediates earlier phases added) to the grown
-    dict; `adds` names the keys the phase introduces (the trace wrapper
-    uses it to derive per-phase shard_map out_specs — see
-    `sharded_trace_phases`). `_shard_step` composes the phases fused, so
-    the compiled scan is the historical program."""
+    """Ordered (name, fn) phase decomposition of the per-device step
+    body. Each fn maps a phase-context dict `px` (per-SE fields under
+    "f", plus intermediates earlier phases added) to the grown dict.
+    `_shard_step` composes the phases fused, each under its
+    `step.<phase>` named scope, so the compiled scan is the historical
+    program."""
     abm = cfg.abm
     n, L, C = spec.n_se, spec.n_lp, spec.cap
     D = spec.n_dev
@@ -958,30 +957,16 @@ def _sharded_phases(cfg, spec: ShardSpec):
             metrics["infected"] = px["infected"].astype(jnp.float32)
         return dict(px, f=f, metrics=metrics)
 
-    halo_adds = (("cellC", "view_pos", "view_lp") if spec.grid is not None
-                 else ("pos_g", "lp_g")) + ("halo_overflow", "halo_n")
+    phases = [("migrate", ph_migrate), ("mobility", ph_mobility),
+              ("halo_exchange", ph_halo), ("proximity", ph_proximity),
+              ("accounting", ph_account)]
     if abm.workload == "epidemic":
-        halo_adds += (("view_eis",) if spec.grid is not None
-                      else ("eis_g",))
-    phases = [
-        ("migrate", ph_migrate,
-         ("wire", "reshard_overflow", "valid", "safe_gid", "n_valid",
-          "all_valid")),
-        ("mobility", ph_mobility,
-         ("sender",) if row_local_mobility(abm) else ("sender", "gid_all")),
-        ("halo_exchange", ph_halo, halo_adds),
-        ("proximity", ph_proximity, ("counts", "grid_overflow")),
-        ("accounting", ph_account,
-         ("safe_lp", "flows", "local", "total", "remote", "migs",
-          "n_evals", "mig_flows", "reparts")),
-    ]
-    if abm.workload == "epidemic":
-        phases.insert(4, ("workload", ph_workload, ("infected",)))
+        phases.insert(4, ("workload", ph_workload))
     if cfg.repartition_every > 0:
-        phases.append(("repartition", ph_repartition, ()))
+        phases.append(("repartition", ph_repartition))
     if cfg.gaia_on:
-        phases.append(("heuristic", ph_heuristic, ()))
-    phases.append(("finalize", ph_finalize, ("metrics",)))
+        phases.append(("heuristic", ph_heuristic))
+    phases.append(("finalize", ph_finalize))
     return phases
 
 
@@ -991,7 +976,7 @@ def _shard_step(f, k_move, k_send, t, mf, cfg, spec: ShardSpec):
     the fused composition of `_sharded_phases`; named scopes annotate
     profiler timelines without adding ops."""
     px = {"f": f, "k_move": k_move, "k_send": k_send, "t": t, "mf": mf}
-    for name, fn, _ in _sharded_phases(cfg, spec):
+    for name, fn in _sharded_phases(cfg, spec):
         with jax.named_scope(f"step.{name}"):
             px = fn(px)
     return px["f"], px["metrics"]
@@ -1037,73 +1022,6 @@ def _batch_field_specs(spec: ShardSpec):
     every per-SE field's spec — the "lp" mesh axis keeps sharding the
     slot dimension, replicas ride along inside each shard."""
     return {k: P(None, *v) for k, v in _field_specs(spec).items()}
-
-
-# ---------------------------------------------------------------------------
-# per-phase trace execution (repro.obs.trace drives this)
-# ---------------------------------------------------------------------------
-
-#: phase-context keys that are per-device *scalars* inside the shard_map
-#: body; at the jit boundary they travel as (D,) arrays sharded P("lp")
-#: (the trace wrapper reshapes () <-> (1,) per device)
-_PER_DEV = frozenset({"reshard_overflow", "halo_overflow", "grid_overflow",
-                      "halo_n", "n_valid"})
-
-#: phase-context keys whose leading axis is the per-device slot (or
-#: view/cell) dimension — sharded P("lp") at the jit boundary
-_SHARDED_PX = frozenset({"valid", "safe_gid", "sender", "counts",
-                         "safe_lp", "cellC", "view_pos", "view_lp",
-                         "view_eis"})
-
-
-def _px_spec(key, cfg, spec: ShardSpec):
-    """PartitionSpec of one phase-context entry at the jit boundary.
-    Everything not explicitly sharded is replicated (psum'd counters,
-    all-gathered id-order arrays, the raw key data, t, mf, wire)."""
-    if key == "f":
-        return _field_specs(spec)
-    if key == "metrics":
-        return _metric_specs(cfg)
-    if key in _PER_DEV or key in _SHARDED_PX:
-        return P("lp")
-    return P()
-
-
-def _wrap_phase(fn, in_keys, out_keys, cfg, spec: ShardSpec, mesh: Mesh):
-    """Jit one phase as its own shard_map program over the full phase
-    context, so the trace executor can time it in isolation. Per-device
-    scalars cross the boundary as (1,)-per-device arrays."""
-    in_specs = {k: _px_spec(k, cfg, spec) for k in in_keys}
-    out_specs = {k: _px_spec(k, cfg, spec) for k in out_keys}
-
-    def inner(px):
-        px = {k: (v.reshape(()) if k in _PER_DEV else v)
-              for k, v in px.items()}
-        out = fn(px)
-        return {k: (out[k].reshape((1,)) if k in _PER_DEV else out[k])
-                for k in out_keys}
-
-    return jax.jit(jax.shard_map(inner, mesh=mesh, in_specs=(in_specs,),
-                                 out_specs=out_specs, check_vma=False))
-
-
-def sharded_trace_phases(cfg, spec: ShardSpec, mesh: Mesh):
-    """Ordered (name, jitted_fn) per-phase programs for the trace
-    executor: each phase of `_sharded_phases` wrapped as its own
-    jit(shard_map) over the accumulated phase context. Phase-split
-    execution reproduces the step's semantics but is a profiling
-    surface, not a bit-identity one — XLA fuses differently across the
-    cut points, so traced runs are not asserted byte-equal to the fused
-    scan (DESIGN.md §Observability)."""
-    keys = frozenset({"f", "k_move", "k_send", "t", "mf"})
-    wrapped = []
-    for name, fn, adds in _sharded_phases(cfg, spec):
-        out_keys = keys | set(adds)
-        wrapped.append((name, _wrap_phase(fn, sorted(keys),
-                                          sorted(out_keys), cfg, spec,
-                                          mesh)))
-        keys = out_keys
-    return wrapped
 
 
 def step_sharded(state, cfg, spec: ShardSpec, mesh: Mesh, mf=None):
@@ -1355,20 +1273,32 @@ def _scan_sharded(state, cfg, n_steps: int, mf=None):
         state, mf_val)
 
 
-def _series_counters(series):
+def _series_counters(series, read=np.asarray):
     from repro.core.engine import series_counters
-    counters = series_counters(series)
-    counters["mean_halo_frac"] = float(series["halo_frac"].mean())
-    counters["shard_overflow"] = float(series["shard_overflow"].sum())
-    wf = np.asarray(series["wire_flows"], np.int64)
+    counters = series_counters(series, read)
+    counters["mean_halo_frac"] = float(read(series["halo_frac"].mean()))
+    counters["shard_overflow"] = float(read(series["shard_overflow"].sum()))
+    wf = read(series["wire_flows"]).astype(np.int64)
     counters["bytes_on_wire"] = float(wf.sum())
     counters["wire_flows"] = wf.sum(axis=0).tolist()
     return counters
 
 
-def run_window_sharded(state, cfg, n_steps: int, mf=None):
-    state, series = _scan_sharded(state, cfg, n_steps, mf=mf)
-    return state, _series_counters(series)
+def walk_slots(cfg) -> int:
+    """Candidate slots the proximity walks of one sharded step visit,
+    summed over the devices: each device walks its `cap` local slots,
+    through the grid walk over its halo view or, without a grid, the
+    dense row sweep against every slot of the mesh."""
+    from repro.core.engine import window_key_cfg
+    spec = make_shard_spec(window_key_cfg(cfg))
+    if spec.grid is not None:
+        per_dev = neighbors.grid_walk(
+            spec.cap, spec.grid.capacity,
+            neighbors.chunk_entries(cfg.abm.mem_budget_mb))[2]
+    else:
+        rows = -(-spec.cap // neighbors.DENSE_CHUNK) * neighbors.DENSE_CHUNK
+        per_dev = rows * spec.n_slots
+    return spec.n_dev * per_dev
 
 
 def run_sharded(key, cfg):
@@ -1417,11 +1347,6 @@ def _batch_replica_counters(series, n_rep: int):
     from repro.core.engine import replica_series
     return [_series_counters(replica_series(series, r))
             for r in range(n_rep)]
-
-
-def run_window_batch_sharded(states, cfg, n_steps: int, mf=None):
-    states, series = _scan_batch_sharded(states, cfg, n_steps, mf=mf)
-    return states, _batch_replica_counters(series, states["t"].shape[0])
 
 
 def unshard_batch(states, spec: ShardSpec):

@@ -11,8 +11,8 @@ Two hard invariants from DESIGN.md §Observability:
 
 Plus the drain correctness surface: the async ring-drain ledger must
 reproduce the per-step series exactly (every step filed once, correct
-stamps) for any window/drain_every alignment, events must carry exact
-step stamps, and the trace exporter must emit Perfetto-loadable JSON.
+stamps) for any window/drain_every alignment, and events must carry
+exact step stamps.
 """
 import dataclasses
 import json
@@ -26,8 +26,7 @@ from repro.core.engine import (EngineConfig, _compiled_window, run,
                                run_window, window_key_cfg)
 from repro.core.heuristics import HeuristicConfig
 from repro.obs import (EVENT_KINDS, JsonlSink, MemorySink, ObsConfig,
-                       Telemetry, ledger_keys, prometheus_text, runtime,
-                       trace_run)
+                       Telemetry, ledger_keys, prometheus_text, runtime)
 from repro.core.service import Engine
 
 ABM = ABMConfig(n_se=96, n_lp=4, area=1000.0, speed=5.0,
@@ -249,34 +248,3 @@ def test_prometheus_text_shape():
     assert "gaia_lcr_mean" in text
     for line in text.splitlines():
         assert line.startswith("# TYPE") or " " in line
-
-
-# ---------------------------------------------------------------------------
-# trace timelines
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("sharded", [False, True],
-                         ids=["oracle", "lp_device"])
-def test_trace_perfetto_structure(sharded):
-    cfg = dataclasses.replace(BASE, timesteps=3, repartition_every=2)
-    n_dev = 1
-    if sharded:
-        cfg = dataclasses.replace(cfg, sharding="lp_device", n_devices=2)
-        n_dev = 2
-    rec = trace_run(cfg, seed=0, warmup=1)
-    doc = json.loads(json.dumps(rec.as_dict()))  # JSON-serializable
-    evs = doc["traceEvents"]
-    spans = [e for e in evs if e["ph"] == "X"]
-    meta = [e for e in evs if e["ph"] == "M"]
-    assert {e["tid"] for e in spans} == set(range(n_dev))
-    assert any(e["name"] == "thread_name" for e in meta)
-    names = {e["name"] for e in spans}
-    assert {"migrate", "mobility", "proximity", "finalize",
-            "repartition"} <= names
-    assert ("halo_exchange" in names) == sharded
-    assert all(e["dur"] >= 0 and "step" in e["args"] for e in spans)
-    if sharded:
-        assert all("n_valid" in e["args"] for e in spans
-                   if e["name"] == "finalize")
-    summ = rec.phase_summary()
-    assert summ["mobility"]["n"] == cfg.timesteps
