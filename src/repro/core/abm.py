@@ -101,10 +101,11 @@ class ABMConfig:
     proximity_backend: str = "grid"  # see PROXIMITY_BACKENDS
     grid_capacity: int = 0  # per-cell member cap; 0 = auto from density
     # hard memory budget (MiB) for the proximity data structures: sizes
-    # the CSR sweep's chunk transients and clamps the auto grid capacity
-    # (neighbors.budget_capacity). 0 = unbudgeted (historical defaults).
-    # A budget too small for the true density is loud, never silent: the
-    # clamped capacity trips `grid_overflow`, exactness is re-checkable.
+    # the proximity sweeps' chunk transients and clamps the auto grid
+    # capacity (neighbors.budget_capacity). 0 = unbudgeted (historical
+    # defaults). A budget too small for the true density is loud, never
+    # silent: the clamped capacity trips `grid_overflow`, exactness is
+    # re-checkable.
     mem_budget_mb: int = 0
     # --- mobility scenario (see module docstring) -----------------------
     mobility: str = "rwp"  # see MOBILITY_MODELS
@@ -588,20 +589,12 @@ def interaction_counts_overflow(pos, lp, sender_mask, cfg: ABMConfig,
     spec = cfg.grid_spec() if backend in ("grid", "pallas_grid") else None
     if backend in ("grid", "pallas_grid") and spec is None:
         backend = "dense"  # world too small to tessellate: exact fallback
-    n = pos.shape[0]
     if backend == "grid":
-        # CSR sweep in sorted cell order (see neighbors.grid_lp_counts):
-        # no member table, no (N, 9 * capacity) candidate matrix — peak
-        # memory is bounded by the chunk budget regardless of N
-        grid = neighbors.build_grid(pos, spec, valid=valid,
-                                    with_table=False)
-        order = grid["order"]
-        out = neighbors.rows_grid_counts(
-            pos, lp, cfg.n_lp, cfg.area, cfg.interaction_range, spec, grid,
-            pos[order], order.astype(jnp.int32), sender_mask[order],
-            neighbors.chunk_entries(cfg.mem_budget_mb))
-        counts = jnp.zeros((n, cfg.n_lp), jnp.int32).at[order].set(out)
-        return counts, grid["overflow"]
+        # every row is swept: the gather-free cell-slab sweep (the row
+        # walk, neighbors.rows_grid_counts, serves row subsets)
+        return neighbors.slab_lp_counts(
+            pos, lp, sender_mask, cfg.n_lp, cfg.area, cfg.interaction_range,
+            spec, valid, neighbors.chunk_entries(cfg.mem_budget_mb))
     if backend == "pallas":
         from repro.kernels.proximity.ops import proximity_lp_counts
         return proximity_lp_counts(pos, lp, sender_mask, cfg.n_lp,
@@ -626,17 +619,21 @@ def interaction_counts(pos, lp, sender_mask, cfg: ABMConfig):
 
 def walk_slots(cfg: ABMConfig) -> int:
     """Candidate slots one call of `interaction_counts_overflow` tests,
-    senders or not: the grid walk's padded rows x 9 x capacity, the
-    Pallas grid kernel's N x 9 x capacity, N^2 for the dense sweeps. The
-    useful share of the walk is the in-range sender pairs over this."""
+    senders or not: the cell-slab sweep's cell rows x (ncell + 2) x 9 x
+    capacity^2, padded to whole chunks (`neighbors.slab_walk`; one
+    receiver layer, a layer more per `capacity` ranks of overflow),
+    the Pallas grid kernel's N x 9 x capacity, N^2 for the dense sweeps.
+    The useful share of the walk is the in-range sender pairs over
+    this."""
     backend = cfg.resolved_backend()
     spec = cfg.grid_spec() if backend in ("grid", "pallas_grid") else None
     n = cfg.n_se
     if spec is None:
         return n * n
     if backend == "grid":
-        return neighbors.grid_walk(
-            n, spec.capacity, neighbors.chunk_entries(cfg.mem_budget_mb))[2]
+        return neighbors.slab_walk(
+            spec.ncell, spec.capacity,
+            neighbors.chunk_entries(cfg.mem_budget_mb))[2]
     return n * 9 * spec.capacity
 
 
@@ -718,23 +715,17 @@ def epidemic_exposure_overflow(pos, labels, query_mask, cfg: ABMConfig,
     (one_hot drops them from the dense path; `valid` keeps them out of
     the grid build).
 
-    This is the proximity phase's candidate walk with a 2-class label
+    This is the proximity phase's cell-slab sweep with a 2-class label
     array instead of the LP map — grid and dense stay bit-identical by
     the same argument, and the one extra sweep is the entire cost of
     the workload."""
     backend = cfg.resolved_backend()
     spec = cfg.grid_spec() if backend == "grid" else None
-    n = pos.shape[0]
     if spec is not None:
-        grid = neighbors.build_grid(pos, spec, valid=valid,
-                                    with_table=False)
-        order = grid["order"]
-        out = neighbors.rows_grid_counts(
-            pos, labels, 2, cfg.area, cfg.interaction_range, spec, grid,
-            pos[order], order.astype(jnp.int32), query_mask[order],
-            neighbors.chunk_entries(cfg.mem_budget_mb))
-        counts = jnp.zeros((n, 2), jnp.int32).at[order].set(out)
-        return counts[:, 1], grid["overflow"]
+        counts, overflow = neighbors.slab_lp_counts(
+            pos, labels, query_mask, 2, cfg.area, cfg.interaction_range,
+            spec, valid, neighbors.chunk_entries(cfg.mem_budget_mb))
+        return counts[:, 1], overflow
     counts = neighbors.dense_lp_counts(pos, labels, query_mask, 2,
                                        cfg.area, cfg.interaction_range)
     return counts[:, 1], jnp.bool_(False)

@@ -14,20 +14,35 @@ Layout (all shapes static so the whole thing JITs and runs under
   * SEs are sorted by cell id (`argsort`), giving contiguous per-cell
     segments; `searchsorted` yields per-cell start offsets and counts —
     a CSR layout of the grid (`order` = column indices, `starts` = row
-    pointers). The hot candidate sweep (`rows_grid_counts`) works
-    directly off this CSR form: for each of the 9 neighbor offsets it
-    gathers one `capacity`-wide segment window per row, chunked under a
-    memory budget, so peak candidate memory is O(chunk * capacity)
-    regardless of N — never the padded (N, 9 * capacity) matrix
-    (`candidate_table`, kept for the Pallas kernels and as a parity
-    oracle in tests).
-  * A fixed-capacity member table `table[c, k]` (padded with -1) can be
-    scattered from the sorted order (`build_grid(..., with_table=True)`;
-    the CSR sweep does not need it). `capacity` must bound the true max
-    cell occupancy for exact results; `build_grid` returns an `overflow`
-    flag so callers outside jit can verify. The auto capacity
-    (`default_capacity`) is sized many Poisson standard deviations above
-    the uniform-density mean, which covers RWP mobility comfortably.
+    pointers), and each SE's rank within its cell.
+  * Sweeps of EVERY row (`slab_lp_counts`: the engine step, the
+    service's `query_lcr`, the epidemic exposure) scatter each live
+    SE's x, y and label once into dense per-cell slabs at (rank, cell),
+    wrap them by one cell on every side and flatten the cells, minor,
+    so the TPU's lanes are full. Each of the 9 neighbour offsets is
+    then one static slice of the candidate slabs, and every (receiver
+    slot, candidate slot) pair of the two cells is tested; one O(N)
+    gather through (rank, cell) brings the counts back to id order.
+    Nothing is gathered per candidate slot, which is what the TPU pays
+    most for; the price is ~cells * 9 * capacity^2 tested pairs, dense
+    vector work.
+  * Sweeps of a row SUBSET (`rows_grid_counts`: the sharded engine's
+    own rows against its halo; `rows_grid_neighbor_ids`: a query
+    batch) walk the CSR form instead: for each of the 9 neighbor
+    offsets every row gathers one `capacity`-wide segment window,
+    chunked under a memory budget, so peak candidate memory is
+    O(chunk * capacity) regardless of N and no work goes to cells no
+    row needs. The padded (N, 9 * capacity) matrix (`candidate_table`)
+    is kept for the Pallas kernels and as a parity oracle in tests.
+  * `capacity` must bound the true max cell occupancy for exact
+    results: members ranked past it are dropped from the slabs, the
+    CSR window and the member table alike, and `build_grid` returns an
+    `overflow` flag so callers can verify. A fixed-capacity member
+    table `table[c, k]` (padded with -1) can be scattered from the
+    sorted order (`build_grid(..., with_table=True)`; no sweep needs
+    it). The auto capacity (`default_capacity`) is sized many Poisson
+    standard deviations above the uniform-density mean, which covers
+    RWP mobility comfortably.
 
 Exactness: candidate cells are distinct (requires `ncell >= 3`, see
 `make_grid_spec`) and the per-pair toroidal distance test is the same
@@ -41,6 +56,7 @@ See DESIGN.md §Adaptations for the grid-vs-dense trade-off discussion.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional
 
@@ -85,15 +101,22 @@ def budget_capacity(ncell: int, mem_budget_mb: int) -> int:
     return max(1, (mem_budget_mb << 19) // (4 * ncell * ncell))
 
 
-def toroidal_d2(a, b, area: float):
-    """Squared toroidal distance between (..., 2) position arrays.
+def toroidal_d2(a, b, area: float, axis: int = -1):
+    """Squared toroidal distance between position arrays whose `axis`
+    holds the (x, y) pair — the last by default, (..., 2).
 
     THE canonical per-pair expression: every backend (dense oracle,
     cell-list, Pallas kernels) must evaluate exactly this so the
-    bit-identical parity contract is meaningful."""
-    d = jnp.abs(a - b)
-    d = jnp.minimum(d, area - d)
-    return d[..., 0] ** 2 + d[..., 1] ** 2
+    bit-identical parity contract is meaningful. The cell-slab sweep
+    keeps its coordinates on the leading axis (`axis=0`), so the cell
+    axis stays minor; the arithmetic is the same."""
+    def sq(k):
+        d = jnp.abs(jax.lax.index_in_dim(a, k, axis, keepdims=False)
+                    - jax.lax.index_in_dim(b, k, axis, keepdims=False))
+        d = jnp.minimum(d, area - d)
+        return d ** 2
+
+    return sq(0) + sq(1)
 
 
 def dense_lp_counts(pos, lp, sender_mask, n_lp: int, area: float,
@@ -288,9 +311,9 @@ def rows_counts_chunked(pos, lp, n_lp: int, area: float, rng: float,
     `row_idx` holds each row's index into the reference arrays (for
     self-exclusion). Rows are processed in chunks sized so the candidate
     matrix stays within a fixed budget, via `lax.map` — peak memory is
-    O(chunk * width) rather than O(R * width). This is the query core
-    shared by the single-device grid backend and the per-shard (halo)
-    path in parallel/lp_shard.py.
+    O(chunk * width) rather than O(R * width). Over `candidate_table`
+    it is the padded-table oracle the sweeps' parity tests compare
+    against.
     """
     r = row_pos.shape[0]
     width = row_cand.shape[1]
@@ -351,8 +374,9 @@ def rows_grid_counts(pos, lp, n_lp: int, area: float, rng: float,
     table was (first `capacity` members in sorted order), so results are
     bit-identical to the dense oracle whenever `grid["overflow"]` is
     False and identically-undercounted (loud, never silent) when it is
-    not. This is the query core of both the single-device grid backend
-    and the per-shard halo path in parallel/lp_shard.py."""
+    not. This is the query core of the per-shard halo path in
+    parallel/lp_shard.py, which sweeps a row subset; sweeps of every row
+    take the cell-slab sweep (`slab_lp_counts`)."""
     n = pos.shape[0]
     nc, cap = spec.ncell, spec.capacity
     order = grid["order"].astype(jnp.int32)
@@ -432,24 +456,213 @@ def rows_grid_neighbor_ids(pos, area: float, rng: float, spec: GridSpec,
     return jnp.concatenate(cols, axis=1)
 
 
+def slab_walk(ncell: int, capacity: int, budget_entries: int = 0) -> tuple:
+    """The chunk rule of the cell-slab sweep (`slab_lp_counts`): (rows,
+    n_chunks, slots). One offset of a chunk tests `rows` whole cell rows
+    x (ncell + 2) positions (each row's two wrapped halo cells included)
+    x capacity^2 slot pairs; the chunk holds every cell row when the
+    budget allows, else as many as the budget affords (at least one),
+    padded to `n_chunks` chunks of `rows`. `slots` is the number of slot
+    pairs one receiver layer of the sweep tests, padding included: 9
+    offsets per position."""
+    budget = budget_entries if budget_entries > 0 else _CHUNK_BUDGET
+    per_row = (ncell + 2) * capacity * capacity
+    rows = max(1, budget // per_row)
+    if rows >= ncell:
+        rows, n_chunks = ncell, 1
+    else:
+        n_chunks = -(-ncell // rows)
+    return rows, n_chunks, rows * n_chunks * len(_NEIGH_OFFSETS) * per_row
+
+
+def _label_packing(capacity: int, n_lp: int) -> tuple:
+    """(bits, per_word, n_words): one offset's count of a label is at
+    most `capacity`, so `bits` hold it without carry; an int32 word
+    packs `per_word` labels' counts, and `n_words` words hold all
+    `n_lp`."""
+    bits = capacity.bit_length()
+    per_word = 31 // bits
+    return bits, per_word, -(-n_lp // per_word)
+
+
+def _slab_chunk(xy, weights, recv, n_lp: int, area: float, rng: float,
+                stride: int, self_pairs: bool):
+    """Per-slot LP histograms of one chunk of whole cell rows.
+
+    The cell axis is the torus grid flattened with row `stride` =
+    ncell + 2: each row carries a wrapped copy of the cell on either
+    side. recv (2, cap, W) holds the receivers' x, y over the chunk's W
+    = rows * stride positions; xy (2, cap, W + 2 * stride + 2) the
+    candidates' x, y over the same positions widened by one halo row
+    and one slot on each side, and weights (n_words, cap, same) their
+    packed label weights (`_label_packing`: 1 << bits * label in the
+    label's word, 0 in empty slots). Returns (cap, n_lp, W) i32: for
+    each receiver slot, the in-range candidates of each label over the
+    3x3 block of cells around it.
+
+    Neighbour (di, dj) of position q is position q + di * stride + dj,
+    so each offset's candidates are one static slice; nothing is
+    gathered or rolled. Each offset sums the packed weights of its
+    in-range candidates in one reduction over the candidate slots (a
+    major axis), then unpacks. `self_pairs` drops the (a, a) diagonal
+    of the centre cell: receivers and candidates are then the same
+    slots."""
+    cap, width = recv.shape[1], recv.shape[2]
+    bits, per_word, _ = _label_packing(cap, n_lp)
+    labels = jnp.arange(n_lp)
+    shift = (bits * (labels % per_word))[None, :, None]
+    acc = jnp.zeros((cap, n_lp, width), jnp.int32)
+    for di, dj in _NEIGH_OFFSETS:
+        at = 1 + (1 + di) * stride + dj
+        c, w = (a[:, :, at:at + width] for a in (xy, weights))
+        # (receiver slot a, candidate slot b, cell)
+        hit = toroidal_d2(recv[:, :, None, :], c[:, None, :, :], area,
+                          axis=0) <= rng * rng
+        if self_pairs and di == 0 and dj == 0:
+            hit = hit & ~jnp.eye(cap, dtype=bool)[:, :, None]
+        packed = jnp.stack([jnp.sum(jnp.where(hit, w[k][None], 0), axis=1)
+                            for k in range(w.shape[0])], axis=1)
+        acc = acc + ((packed[:, labels // per_word] >> shift)
+                     & ((1 << bits) - 1))
+    return acc
+
+
+def _cell_ranks(pos, spec: GridSpec, valid=None):
+    """(cell, rank, max_rank): each SE's cell id (`cell_ids`; the
+    virtual id ncell^2 for rows `valid` masks out) and its rank in the
+    stable sort by cell — its position within the cell's CSR segment of
+    `build_grid` — in id order, and the largest live rank (-1 with no
+    live row). Two sorts and a running maximum: no gather or scatter."""
+    n = pos.shape[0]
+    ncells = spec.ncell * spec.ncell
+    cell = cell_ids(pos, spec)
+    if valid is not None:
+        cell = jnp.where(valid, cell, ncells)
+    iota = jnp.arange(n, dtype=jnp.int32)
+    cell_sorted, order = jax.lax.sort((cell, iota), num_keys=1)
+    first = cell_sorted != jnp.concatenate([cell_sorted[:1] - 1,
+                                            cell_sorted[:-1]])
+    rank_sorted = iota - jax.lax.cummax(jnp.where(first, iota, 0))
+    max_rank = jnp.where(cell_sorted < ncells, rank_sorted, -1).max()
+    rank = jax.lax.sort((order, rank_sorted), num_keys=1)[1]
+    return cell, rank, max_rank
+
+
+@functools.partial(jax.jit, static_argnames=("n_lp", "area", "rng", "spec",
+                                             "budget_entries"))
+def slab_lp_counts(pos, lp, sender_mask, n_lp: int, area: float, rng: float,
+                   spec: GridSpec, valid=None, budget_entries: int = 0):
+    """Cell-list LP histogram of every row, by a gather-free sweep of
+    dense per-cell slabs. Returns (counts, overflow), counts (N, n_lp)
+    i32 bit-identical to `dense_lp_counts` when overflow is False, and
+    overflow the flag `build_grid` raises.
+
+    Each live SE is ranked within its cell as `build_grid`'s sort ranks
+    it (`_cell_ranks`); O(N) scatters lay x, y and packed label
+    weights into slabs of shape (capacity, ncell, ncell), empty slots
+    weightless (ranks >= capacity are dropped: the same members the CSR
+    window and `candidate_table` drop). The slabs are wrapped by one
+    cell on every side and flattened, cells minor (`_slab_chunk`), so
+    each of the 9 neighbour offsets is a static shift; every (receiver
+    slot, candidate slot) pair of the two cells is tested with
+    `toroidal_d2`. One O(N) gather through (rank, cell) returns the
+    histograms to id order.
+
+    Receivers ranked past `capacity` (only under overflow) are swept in
+    further layers of `capacity` ranks against the same candidate slabs,
+    so every row gets the counts the CSR walk gives it. Without
+    overflow the layer loop never runs. Cell rows are swept in
+    `lax.map` chunks when one offset's capacity^2 slot pairs over all
+    cells exceed `budget_entries` (`slab_walk`); every budget gives the
+    same integers.
+
+    `valid` masks rows out of the grid (the open-world engine's dead
+    slots): they occupy no slot and get no counts, so the caller must
+    not make them senders."""
+    n = pos.shape[0]
+    nc, cap = spec.ncell, spec.capacity
+    stride = nc + 2
+    cell, rank, max_rank = _cell_ranks(pos, spec, valid)
+    live = cell < nc * nc
+    cx, cy = cell // nc, cell % nc
+    # each row's receiver position on the wrapped, flattened cell axis
+    place = jnp.where(live, cx * stride + cy + 1, 0)
+    rows, n_chunks, _ = slab_walk(nc, cap, budget_entries)
+    pad_rows = n_chunks * rows - nc
+
+    def slab(fields, k):
+        """(len(fields), cap, (nc + 2 + pad_rows) * stride + 2): layer
+        k's ranks [k * cap, (k + 1) * cap) wrapped by a cell on every
+        side, then empty cell rows up to whole chunks, and one empty
+        slot at either end; other ranks drop."""
+        inside = live & (rank >= k * cap) & (rank < (k + 1) * cap)
+        slot = jnp.where(inside, rank - k * cap, cap)
+        # one scatter per field: a field axis in the scatter's window
+        # makes XLA lay the slab out field-minor
+        s = jnp.stack([jnp.zeros((cap, nc, nc), f.dtype).at[slot, cx, cy]
+                       .set(f, mode="drop") for f in fields])
+        s = jnp.pad(s, ((0, 0), (0, 0), (1, 1), (1, 1)), mode="wrap")
+        s = jnp.pad(s, ((0, 0), (0, 0), (0, pad_rows), (0, 0)))
+        s = jnp.pad(s.reshape(len(fields), cap, -1), ((0, 0), (0, 0), (1, 1)))
+        return s, inside, slot
+
+    bits, per_word, n_words = _label_packing(cap, n_lp)
+    word = lp // per_word
+    weight = jnp.left_shift(jnp.int32(1), bits * (lp - word * per_word))
+    weights = [jnp.where((lp >= 0) & (word == k), weight, 0)
+               for k in range(n_words)]
+    xy = [pos[:, 0].astype(jnp.float32), pos[:, 1].astype(jnp.float32)]
+    cand_xy, inside0, slot0 = slab(xy, 0)
+    cand_w = slab(weights, 0)[0]
+    width = rows * stride
+
+    def sweep(recv, self_pairs):
+        # receivers: the cell rows of the wrapped slab, halo columns
+        # included (their counts are never read)
+        recv = recv[:, :, 1 + stride:1 + stride + n_chunks * width]
+        if n_chunks == 1:
+            return _slab_chunk(cand_xy, cand_w, recv, n_lp, area, rng,
+                               stride, self_pairs)
+
+        def one(k):
+            def window(a, start, size):
+                return jax.lax.dynamic_slice_in_dim(a, start, size, axis=2)
+
+            size = width + 2 * stride + 2
+            return _slab_chunk(window(cand_xy, k * width, size),
+                               window(cand_w, k * width, size),
+                               window(recv, k * width, width), n_lp, area,
+                               rng, stride, self_pairs)
+
+        out = jax.lax.map(one, jnp.arange(n_chunks))
+        return jnp.moveaxis(out, 0, 2).reshape(cap, n_lp, -1)
+
+    def back(per_slot, inside, slot, out):
+        got = per_slot[jnp.minimum(slot, cap - 1), :, place]
+        return jnp.where(inside[:, None], got, out)
+
+    out = back(sweep(cand_xy, True), inside0, slot0,
+               jnp.zeros((n, n_lp), jnp.int32))
+
+    def layer(carry):
+        k, out = carry
+        recv, inside, slot = slab(xy, k)
+        return k + 1, back(sweep(recv, False), inside, slot, out)
+
+    _, out = jax.lax.while_loop(lambda carry: carry[0] * cap <= max_rank,
+                                layer, (jnp.int32(1), out))
+    return jnp.where(sender_mask[:, None], out, 0), max_rank >= cap
+
+
 def grid_lp_counts(pos, lp, sender_mask, n_lp: int, area: float, rng: float,
                    spec: GridSpec, budget_entries: int = 0):
     """Cell-list version of the dense LP histogram — bit-identical output.
 
     counts[i, l] = #{j != i : toroidal_dist(i, j) <= rng, lp[j] == l},
-    zeroed for non-senders. Delegates to the CSR segment sweep with every
-    agent as a row, visited in sorted cell order (the sort is free — the
-    grid build computes it — and gives the sweep's segment gathers
-    spatial locality); the scatter back to id order is exact, and the
-    counts are integers, so row order never perturbs the result.
-    """
-    n = pos.shape[0]
-    grid = build_grid(pos, spec, with_table=False)
-    order = grid["order"]
-    out = rows_grid_counts(pos, lp, n_lp, area, rng, spec, grid,
-                           pos[order], order.astype(jnp.int32),
-                           sender_mask[order], budget_entries)
-    return jnp.zeros((n, n_lp), jnp.int32).at[order].set(out)
+    zeroed for non-senders: the cell-slab sweep (`slab_lp_counts`)
+    with every agent a row."""
+    return slab_lp_counts(pos, lp, sender_mask, n_lp, area, rng, spec,
+                          budget_entries=budget_entries)[0]
 
 
 def halo_mask(cell_ref, row_cell, row_valid, spec: GridSpec):

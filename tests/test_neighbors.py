@@ -9,6 +9,8 @@ tessellate (dense fallback), and clustered (non-uniform) positions that
 stress the fixed per-cell capacity.
 """
 import dataclasses
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -191,11 +193,12 @@ def test_invalid_backend_rejected():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("budget_entries", [1, 37, 4096])
+@pytest.mark.parametrize("budget_entries", [1, 37, 4096, 26_000])
 def test_csr_chunk_budget_bit_identical(budget_entries):
     """The lax.map chunk size is a pure memory knob: any budget — down to
-    one candidate entry (one row) per chunk, forcing ~200 sequential
-    chunks — must reproduce the dense oracle bit-for-bit."""
+    one candidate entry (one cell row per chunk, 12 sequential chunks),
+    or 5 cell rows per chunk with the last chunk padded — must reproduce
+    the dense oracle bit-for-bit."""
     n, n_lp, area, rng = 200, 4, 1000.0, 80.0
     pos, lp, sender = _case(9, n, n_lp, area, rng)
     cfg = ABMConfig(n_se=n, n_lp=n_lp, area=area, interaction_range=rng)
@@ -317,3 +320,177 @@ def test_engine_budget_propagates_to_abm():
     cfg2 = EngineConfig(abm=abm2, heuristic=HeuristicConfig(),
                         mem_budget_mb=64)
     assert cfg2.abm.mem_budget_mb == 8
+
+
+# ---------------------------------------------------------------------------
+# Cell-slab sweep (all-rows sweeps): the dense oracle and the row walk agree
+# ---------------------------------------------------------------------------
+
+
+def _row_walk(pos, lp, sender, n_lp, area, rng, spec, valid=None):
+    """`rows_grid_counts` with every agent a row, in id order."""
+    grid = neighbors.build_grid(pos, spec, valid=valid, with_table=False)
+    idx = jnp.arange(pos.shape[0], dtype=jnp.int32)
+    return neighbors.rows_grid_counts(pos, lp, n_lp, area, rng, spec, grid,
+                                      pos, idx, sender)
+
+
+def _slab_case(name):
+    """(pos, labels, sender, n_lp, area, rng, spec, valid, exact)."""
+    k = jax.random.key(31)
+    if name == "uniform_seam":
+        n, n_lp, area, rng = 400, 4, 1000.0, 60.0
+        pos, lp, sender = _case(41, n, n_lp, area, rng)
+        # a third of the agents on the wrap lines of both axes
+        seam = (pos * 0.04 - area * 0.02) % area
+        pos = jnp.where((jnp.arange(n) % 3 == 0)[:, None], seam, pos)
+        return (pos, lp, sender, n_lp, area, rng,
+                neighbors.make_grid_spec(n, area, rng, capacity=n), None,
+                True)
+    if name == "ncell3":
+        n, n_lp, area, rng = 90, 3, 300.0, 100.0
+        pos, lp, sender = _case(42, n, n_lp, area, rng)
+        spec = neighbors.make_grid_spec(n, area, rng)
+        assert spec.ncell == 3
+        return pos, lp, sender, n_lp, area, rng, spec, None, True
+    if name == "clustered_explicit_capacity":
+        n, n_lp, area, rng = 150, 4, 1000.0, 100.0
+        pos = jax.random.uniform(k, (n, 2), maxval=40.0)  # one corner cell
+        lp = jax.random.randint(jax.random.fold_in(k, 1), (n,), 0, n_lp)
+        sender = jnp.ones((n,), bool)
+        return (pos, lp, sender, n_lp, area, rng,
+                neighbors.make_grid_spec(n, area, rng, capacity=n), None,
+                True)
+    if name == "overflow":
+        # the layout of test_csr_overflow_drop_set_matches_table_oracle
+        n, n_lp, area, rng = 240, 4, 1000.0, 100.0
+        k = jax.random.key(21)
+        centers = jnp.array([[100.0, 100.0], [500.0, 900.0],
+                             [900.0, 400.0]])
+        pos = (centers[jnp.arange(n) % 3]
+               + jax.random.normal(k, (n, 2)) * 15.0) % area
+        lp = jax.random.randint(jax.random.fold_in(k, 1), (n,), 0, n_lp)
+        sender = jnp.ones((n,), bool)
+        return (pos, lp, sender, n_lp, area, rng,
+                neighbors.make_grid_spec(n, area, rng), None, False)
+    if name == "open_world_dead_rows":
+        n, n_lp, area, rng = 300, 4, 1000.0, 80.0
+        pos, lp, sender = _case(43, n, n_lp, area, rng)
+        valid = jax.random.bernoulli(jax.random.fold_in(k, 5), 0.7, (n,))
+        # dead rows: lp -1 and never senders, as the open-world engine
+        # keeps them; a cramped capacity that only the live rows fit
+        lp = jnp.where(valid, lp, -1)
+        spec = neighbors.make_grid_spec(n, area, rng)
+        live_max = int(np.bincount(np.asarray(
+            neighbors.cell_ids(pos, spec))[np.asarray(valid)]).max())
+        spec = dataclasses.replace(spec, capacity=live_max)
+        return pos, lp, sender & valid, n_lp, area, rng, spec, valid, True
+    if name == "epidemic_two_labels":
+        n, area, rng = 300, 1000.0, 80.0
+        pos, _, query = _case(44, n, 2, area, rng)
+        valid = jax.random.bernoulli(jax.random.fold_in(k, 6), 0.8, (n,))
+        infectious = jax.random.bernoulli(jax.random.fold_in(k, 7), 0.3,
+                                          (n,))
+        labels = jnp.where(valid, infectious.astype(jnp.int32), -1)
+        return (pos, labels, query & valid, 2, area, rng,
+                neighbors.make_grid_spec(n, area, rng), valid, True)
+    raise ValueError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "uniform_seam", "ncell3", "clustered_explicit_capacity", "overflow",
+    "open_world_dead_rows", "epidemic_two_labels"])
+def test_slab_sweep_matches_dense_and_row_walk(name):
+    """The cell-slab sweep equals the row walk over all rows, bit for
+    bit, and the dense oracle wherever no cell overflows; under
+    overflow it drops exactly the members `candidate_table` drops (and
+    still counts for the rows ranked past capacity)."""
+    pos, lp, sender, n_lp, area, rng, spec, valid, exact = _slab_case(name)
+    got, overflow = neighbors.slab_lp_counts(pos, lp, sender, n_lp, area,
+                                             rng, spec, valid)
+    got = np.asarray(got)
+    assert bool(overflow) == (not exact)
+    walk = _row_walk(pos, lp, sender, n_lp, area, rng, spec, valid)
+    np.testing.assert_array_equal(got, np.asarray(walk))
+    ref = np.asarray(neighbors.dense_lp_counts(pos, lp, sender, n_lp, area,
+                                               rng))
+    if exact:
+        np.testing.assert_array_equal(got, ref)
+    else:
+        cand, _ = neighbors.candidate_table(pos, spec)
+        table = neighbors.rows_counts_chunked(
+            pos, lp, n_lp, area, rng, pos,
+            jnp.arange(pos.shape[0], dtype=jnp.int32), sender, cand)
+        np.testing.assert_array_equal(got, np.asarray(table))
+        assert got.sum() < ref.sum()
+    if name == "epidemic_two_labels":
+        from repro.core.abm import epidemic_exposure_overflow
+        cfg = ABMConfig(n_se=pos.shape[0], n_lp=4, area=area,
+                        interaction_range=rng, workload="epidemic")
+        assert cfg.grid_spec() == spec
+        exposure, ovf = epidemic_exposure_overflow(pos, lp, sender, cfg,
+                                                   valid=valid)
+        np.testing.assert_array_equal(np.asarray(exposure), ref[:, 1])
+        assert not bool(ovf)
+
+
+def _wide_gathers(hlo: str, n: int) -> list:
+    """Gathers of the HLO text that fetch more than `n` index rows: the
+    product of the output dims that are not slice (offset) dims."""
+    wide = []
+    for line in hlo.splitlines():
+        m = re.search(r"= \w+\[([\d,]*)\]\S* gather\("
+                      r".*offset_dims=\{([\d,]*)\}", line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        offset = {int(d) for d in m.group(2).split(",") if d}
+        rows = math.prod(d for i, d in enumerate(dims) if i not in offset)
+        if rows > n:
+            wide.append(line.strip()[:160])
+    return wide
+
+
+def test_grid_sweep_has_no_wide_gather():
+    """The all-rows grid sweep gathers nothing per candidate slot: no
+    gather of the lowered `interaction_counts_overflow` fetches more
+    than N index rows (the row walk, for contrast, fetches N x capacity
+    per neighbour cell)."""
+    from repro.core.abm import interaction_counts_overflow
+
+    n = 600
+    cfg = ABMConfig(n_se=n, n_lp=4, area=2000.0, interaction_range=100.0)
+    spec = cfg.grid_spec()
+    assert spec.ncell ** 2 < n
+    args = (jax.ShapeDtypeStruct((n, 2), jnp.float32),
+            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n,), jnp.bool_))
+
+    def hlo(fn):
+        return jax.jit(fn).lower(*args).as_text(dialect="hlo")
+
+    assert _wide_gathers(hlo(lambda p, l, s: interaction_counts_overflow(
+        p, l, s, cfg)), n) == []
+    assert _wide_gathers(hlo(lambda p, l, s: _row_walk(
+        p, l, s, cfg.n_lp, cfg.area, cfg.interaction_range, spec)), n)
+
+
+def test_slab_walk_counts_padded_cells():
+    """`slab_walk`'s slot count is cell rows x (ncell + 2) positions (the
+    two wrapped halo cells of each row included) x 9 x capacity^2,
+    padded to whole chunks of cell rows, and is what `abm.walk_slots`
+    reports."""
+    from repro.core.abm import walk_slots
+
+    assert neighbors.slab_walk(40, 35) == (40, 1, 40 * 42 * 9 * 35 * 35)
+    # one cell row of 14 x 19^2 slot pairs per chunk, 12 chunks
+    assert neighbors.slab_walk(12, 19, 1)[:2] == (1, 12)
+    # 4 rows per chunk: 3 chunks, no padding
+    assert neighbors.slab_walk(12, 19, 22_000) == (4, 3,
+                                                   12 * 14 * 9 * 19 * 19)
+    # 5 rows per chunk: 3 chunks, the last padded by 3 empty cell rows
+    assert neighbors.slab_walk(12, 19, 26_000) == (5, 3,
+                                                   15 * 14 * 9 * 19 * 19)
+    cfg = ABMConfig(n_se=10_000, n_lp=4, area=10_000.0,
+                    interaction_range=250.0)
+    assert walk_slots(cfg) == 18_522_000

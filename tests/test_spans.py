@@ -7,7 +7,7 @@ execution layers:
 * the span tree of each call: its stages in order, nested in the call's
   span, every span of the call under the one `call` id;
 * the counters they carry equal the counts worked out here: the
-  proximity walk's candidate slots (`walk_slots`), the counter read's
+  proximity sweep's candidate slots (`walk_slots`), the counter read's
   device-to-host transfers (`fetches`), the churn batch sizes;
 * spans never touch the programs: results are bit-identical with the
   profiler on and off, the lowered window program is the same, and the
@@ -98,14 +98,16 @@ def engine(layer: str) -> Engine:
 
 
 def walk_slots(cfg) -> int:
-    """Rows the proximity walk visits per step x 9 cells x the cell
-    capacity: every slot of the universe on one device, every device's
-    `cap` local slots on the sharded layer (one chunk at this size)."""
-    rows = cfg.abm.n_se
+    """Candidate slots the proximity sweep tests per step (one chunk at
+    this size): on one device the cell-slab sweep, cells (with each cell
+    row's two wrapped halo cells) x 9 neighbour cells x capacity^2 slot
+    pairs; on the sharded layer the row walk of every device's `cap`
+    local slots, rows x 9 cells x capacity."""
+    grid = cfg.abm.grid_spec()
     if cfg.sharding == "lp_device":
         spec = lp_shard.make_shard_spec(cfg)
-        rows = spec.n_dev * spec.cap
-    return rows * 9 * cfg.abm.grid_spec().capacity
+        return spec.n_dev * spec.cap * 9 * grid.capacity
+    return grid.ncell * (grid.ncell + 2) * 9 * grid.capacity ** 2
 
 
 @pytest.mark.parametrize("call", list(CALLS))
