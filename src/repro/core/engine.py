@@ -529,29 +529,93 @@ class HostReads:
         return np.asarray(x)
 
 
-def series_counters(series, read=np.asarray) -> dict:
-    """Aggregate a per-step metrics series into run counters — the one
-    place the counter/series key contract lives (the sharded runner
-    layers its extra metrics on top). Matrix-valued series (the per-pair
-    flow counters) aggregate to nested lists in int64 so long runs
-    cannot wrap int32. Every value reaches the host through `read`, one
-    transfer per call."""
-    counters = {k: float(read(series[k].sum())) for k in
-                ("local_msgs", "remote_msgs", "migrations", "heu_evals")}
-    counters["mean_lcr"] = float(read(series["lcr"].mean()))
-    if "pop" in series:
-        counters["mean_pop"] = float(read(series["pop"].mean()))
-    if "infected" in series:
-        counters["mean_infected"] = float(read(series["infected"].mean()))
-        counters["final_infected"] = float(read(series["infected"][-1]))
-    for k in ("grid_overflow", "repartitions"):
-        if k in series:
-            counters[k] = float(read(series[k].sum()))
-    for k in ("lp_flows", "mig_flows"):
-        if k in series:
-            counters[k] = read(series[k]).sum(
-                axis=0, dtype=np.int64).tolist()
+#: the window summary's scalar counters, in packing order: (counter,
+#: series key, reduction over the step axis). A key the series lacks
+#: drops out, so one layout adapts to every config: open world's `pop`,
+#: the epidemic's `infected`, the sharded layer's `halo_frac` and
+#: `shard_overflow`.
+SUMMARY_SCALARS = (
+    ("local_msgs", "local_msgs", "sum"),
+    ("remote_msgs", "remote_msgs", "sum"),
+    ("migrations", "migrations", "sum"),
+    ("heu_evals", "heu_evals", "sum"),
+    ("mean_lcr", "lcr", "mean"),
+    ("mean_pop", "pop", "mean"),
+    ("mean_infected", "infected", "mean"),
+    ("final_infected", "infected", "last"),
+    ("grid_overflow", "grid_overflow", "sum"),
+    ("repartitions", "repartitions", "sum"),
+    ("mean_halo_frac", "halo_frac", "mean"),
+    ("shard_overflow", "shard_overflow", "sum"),
+)
+
+#: the per-pair int32 flow series: the summary carries them per step and
+#: the host sums them in int64, so that long windows cannot wrap int32
+SUMMARY_FLOWS = ("lp_flows", "mig_flows", "wire_flows")
+
+_REDUCE = {"sum": lambda x: x.sum(), "mean": lambda x: x.mean(),
+           "last": lambda x: x[-1]}
+
+
+def series_shapes(series) -> dict:
+    """The per-step series' shapes: all `unpack` needs of the series."""
+    return {k: tuple(v.shape) for k, v in series.items()}
+
+
+def window_summary(series):
+    """Traceable half of the counter read: one window's counters as one
+    flat int32 buffer, the scalars first (their f32 bits) and then each
+    flow series whole. Each scalar is the same jnp reduction over the
+    step axis that an eager read of the series would run."""
+    scalars = jnp.stack([_REDUCE[how](series[k])
+                         for _, k, how in SUMMARY_SCALARS if k in series])
+    return jnp.concatenate(
+        [jax.lax.bitcast_convert_type(scalars.astype(jnp.float32),
+                                      jnp.int32)]
+        + [series[k].reshape(-1) for k in SUMMARY_FLOWS if k in series])
+
+
+def unpack(buf, shapes) -> dict:
+    """Host half of the counter read: a fetched `window_summary` buffer
+    -> the run-counter dict, given the series' `shapes`. Flow matrices
+    sum to nested int64 lists; `wire_flows` adds their total,
+    `bytes_on_wire`."""
+    buf = np.asarray(buf)
+    names = [c for c, k, _ in SUMMARY_SCALARS if k in shapes]
+    at = len(names)
+    counters = dict(zip(names, buf[:at].view(np.float32).tolist()))
+    for k in SUMMARY_FLOWS:
+        if k in shapes:
+            size = int(np.prod(shapes[k]))
+            flows = buf[at:at + size].reshape(shapes[k]).sum(
+                axis=0, dtype=np.int64)
+            at += size
+            counters[k] = flows.tolist()
+            if k == "wire_flows":
+                counters["bytes_on_wire"] = float(flows.sum())
     return counters
+
+
+def series_counters(series, read=np.asarray) -> dict:
+    """Aggregate a per-step metrics series into run counters, eagerly:
+    `unpack(read(window_summary(series)))`, the one counter/series key
+    contract, in one transfer. The window programs carry the same
+    summary as an output of their own (`_compiled_window`)."""
+    return unpack(read(window_summary(series)), series_shapes(series))
+
+
+def batch_summary(series):
+    """`window_summary` of each replica of a batched (T, R, ...) series,
+    stacked to (R, K): one buffer for the whole batch."""
+    return jnp.stack([window_summary(replica_series(series, r))
+                      for r in range(series["lcr"].shape[1])])
+
+
+def unpack_batch(buf, shapes) -> list:
+    """Per-replica counters from a fetched `batch_summary` buffer, given
+    the batched series' (T, R, ...) `shapes`."""
+    rep = {k: s[:1] + s[2:] for k, s in shapes.items()}
+    return [unpack(row, rep) for row in np.asarray(buf)]
 
 
 def _trace_guard(state, cfg: EngineConfig, n_steps: int) -> None:
@@ -620,12 +684,14 @@ def clear_compiled_caches() -> None:
 def _compiled_window_cached(cfg: EngineConfig, n_steps: int):
     if not cfg.obs.enabled:
         # telemetry off: this branch is chosen by a static Python `if`,
-        # so the traced program is byte-for-byte the historical one —
-        # no ring carry, no callback, no extra outputs
+        # so the traced scan is byte-for-byte the historical one — no
+        # ring carry, no callback; the one extra output is the window's
+        # counter summary, reduced from the series after the scan
         def fn(state, mf):
             def body(s, _):
                 return step(s, cfg, mf=mf)
-            return jax.lax.scan(body, state, None, length=n_steps)
+            state, series = jax.lax.scan(body, state, None, length=n_steps)
+            return state, series, window_summary(series)
         return jax.jit(fn)
 
     # telemetry on: thread a (drain_every, K) f32 ring through the scan
@@ -658,12 +724,15 @@ def _compiled_window_cached(cfg: EngineConfig, n_steps: int):
         ring0 = jnp.full((de, n_cols), -1.0, jnp.float32)
         (s, ring), series = jax.lax.scan(body, (state, ring0), None,
                                          length=n_steps)
-        return s, ring, series
+        return s, ring, series, window_summary(series)
     return jax.jit(fn)
 
 
 def _compiled_window(cfg: EngineConfig, n_steps: int):
-    """One jitted n_steps-scan per config shape, with MF dynamic.
+    """One jitted n_steps-scan per config shape, with MF dynamic. It
+    returns (state, series, summary), `ring` before `series` with
+    telemetry on: `summary` is the window's `window_summary`, so that
+    the counters reach the host in one transfer.
 
     Eager `lax.scan` re-traces (and recompiles) on every call because
     the body closure is fresh each time; memoizing the jitted scan by
@@ -687,8 +756,8 @@ def walk_slots(cfg: EngineConfig) -> int:
 
 def _dispatch_window(state, cfg: EngineConfig, n_steps: int, mf=None):
     """Enqueue one n_steps window on either execution layer; returns
-    (state, per-step series) without waiting for the device (with
-    telemetry on, after the ledger's tail is flushed)."""
+    (state, per-step series, counter summary) without waiting for the
+    device (with telemetry on, after the ledger's tail is flushed)."""
     _trace_guard(state, cfg, n_steps)
     if cfg.sharding == "lp_device":
         from repro.parallel import lp_shard
@@ -697,19 +766,11 @@ def _dispatch_window(state, cfg: EngineConfig, n_steps: int, mf=None):
     mf_val = jnp.float32(cfg.heuristic.mf if mf is None else mf)
     if cfg.obs.enabled:
         t0 = int(state["t"])
-        state, ring, series = _compiled_window(cfg, n_steps)(state, mf_val)
+        state, ring, series, summary = _compiled_window(cfg, n_steps)(
+            state, mf_val)
         obs_runtime.flush_tail(ring, t0, t0 + n_steps)
-        return state, series
+        return state, series, summary
     return _compiled_window(cfg, n_steps)(state, mf_val)
-
-
-def window_counters(series, cfg: EngineConfig, read=np.asarray) -> dict:
-    """The counter read of one window's series, on either execution
-    layer (see `series_counters`)."""
-    if cfg.sharding == "lp_device":
-        from repro.parallel import lp_shard
-        return lp_shard._series_counters(series, read)
-    return series_counters(series, read)
 
 
 def _run_window(state, cfg: EngineConfig, n_steps: int, mf=None):
@@ -720,8 +781,8 @@ def _run_window(state, cfg: EngineConfig, n_steps: int, mf=None):
     dynamic argument: no recompilation between windows). Sharded states
     (from a sharded init_engine) advance through the sharded step and
     stay slot-major."""
-    state, series = _dispatch_window(state, cfg, n_steps, mf=mf)
-    return state, window_counters(series, cfg)
+    state, series, summary = _dispatch_window(state, cfg, n_steps, mf=mf)
+    return state, unpack(summary, series_shapes(series))
 
 
 def _run(key, cfg: EngineConfig):
@@ -733,15 +794,9 @@ def _run(key, cfg: EngineConfig):
     if cfg.sharding == "lp_device":
         from repro.parallel import lp_shard
         return lp_shard.run_sharded(key, cfg)
-    st = _init_engine(key, cfg)
-    if cfg.obs.enabled:
-        st, ring, series = _compiled_window(cfg, cfg.timesteps)(
-            st, jnp.float32(cfg.heuristic.mf))
-        obs_runtime.flush_tail(ring, 0, cfg.timesteps)
-    else:
-        st, series = _compiled_window(cfg, cfg.timesteps)(
-            st, jnp.float32(cfg.heuristic.mf))
-    counters = series_counters(series)
+    st, series, summary = _dispatch_window(_init_engine(key, cfg), cfg,
+                                           cfg.timesteps)
+    counters = unpack(summary, series_shapes(series))
     counters["migration_ratio"] = _migration_ratio(counters, cfg)
     return st, series, counters
 
@@ -801,38 +856,33 @@ def _compiled_batch_cached(cfg: EngineConfig, n_steps: int):
     def fn(states, mfs):
         def body(s, _):
             return jax.vmap(lambda st, m: step(st, cfg, mf=m))(s, mfs)
-        return jax.lax.scan(body, states, None, length=n_steps)
+        states, series = jax.lax.scan(body, states, None, length=n_steps)
+        return states, series, batch_summary(series)
     return jax.jit(fn)
 
 
 def _compiled_batch(cfg: EngineConfig, n_steps: int):
     """One jitted batched scan per config shape: `jax.vmap` of the
     single-replica step over the leading replica axis, MF dynamic and
-    per-replica. jit re-specializes per replica count, so the cache key
-    stays (config shape, n_steps) like `_compiled_window`. Batched
-    scans are un-instrumented (strip_obs): the ledger covers the
-    single-replica resident paths."""
+    per-replica; it returns (states, series, `batch_summary`). jit
+    re-specializes per replica count, so the cache key stays (config
+    shape, n_steps) like `_compiled_window`. Batched scans are
+    un-instrumented (strip_obs): the ledger covers the single-replica
+    resident paths."""
     return _compiled_batch_cached(window_key_cfg(strip_obs(cfg)), n_steps)
 
 
 def _dispatch_window_batch(states, cfg: EngineConfig, n_steps: int,
                            mf=None):
     """Enqueue one batched n_steps window of R stacked replicas on
-    either execution layer; returns (states, (T, R, ...) series)
-    without waiting for the device."""
+    either execution layer; returns (states, (T, R, ...) series,
+    (R, K) counter summary) without waiting for the device."""
     _trace_guard(states, cfg, n_steps)
     if cfg.sharding == "lp_device":
         from repro.parallel import lp_shard
         return lp_shard._scan_batch_sharded(states, cfg, n_steps, mf=mf)
     n_rep = states["t"].shape[0]
     return _compiled_batch(cfg, n_steps)(states, _mf_vector(cfg, mf, n_rep))
-
-
-def batch_counters(series, cfg: EngineConfig, read=np.asarray) -> list:
-    """Per-replica counter reads of one batched window's series."""
-    n_rep = series["lcr"].shape[1]
-    return [window_counters(replica_series(series, r), cfg, read)
-            for r in range(n_rep)]
 
 
 def _run_window_batch(states, cfg: EngineConfig, n_steps: int, mf=None):
@@ -842,8 +892,9 @@ def _run_window_batch(states, cfg: EngineConfig, n_steps: int, mf=None):
     §5.5 tuner descends each replica's MF independently, so MF rides as
     a per-replica dynamic argument of the one compiled scan. Returns
     (states, [per-replica counters])."""
-    states, series = _dispatch_window_batch(states, cfg, n_steps, mf=mf)
-    return states, batch_counters(series, cfg)
+    states, series, summary = _dispatch_window_batch(states, cfg, n_steps,
+                                                     mf=mf)
+    return states, unpack_batch(summary, series_shapes(series))
 
 
 def _run_batch(cfg: EngineConfig, seeds):
@@ -865,14 +916,11 @@ def _run_batch(cfg: EngineConfig, seeds):
     if cfg.sharding == "lp_device":
         from repro.parallel import lp_shard
         return lp_shard.run_batch_sharded(cfg, seeds)
-    states = _init_batch(cfg, seeds)
-    states, series = _compiled_batch(cfg, cfg.timesteps)(
-        states, _mf_vector(cfg, None, len(seeds)))
-    reps = []
-    for r in range(len(seeds)):
-        c = series_counters(replica_series(series, r))
+    states, series, summary = _dispatch_window_batch(
+        _init_batch(cfg, seeds), cfg, cfg.timesteps)
+    reps = unpack_batch(summary, series_shapes(series))
+    for c in reps:
         c["migration_ratio"] = _migration_ratio(c, cfg)
-        reps.append(c)
     return states, series, reps
 
 

@@ -152,29 +152,34 @@ class Engine:
         (per-replica vector allowed when batched) — the §5.5 tuners'
         contract, unchanged.
 
+        The window program computes its own counter summary
+        (`engine.window_summary`, one flat buffer); its copy to the host
+        starts as soon as the window is enqueued, and the counters are
+        unpacked from that one transfer.
+
         Spans: `gaia.step` (n steps) holds `dispatch` (the window
-        program enqueued; `walk_slots` per step), `wait` (until the
-        device is done) and `readback` (the counter read; `fetches`
-        device-to-host transfers)."""
+        program enqueued and the summary's copy started; `walk_slots`
+        per step), `wait` (until the summary is ready) and `readback`
+        (the summary read and unpacked; `fetches` device-to-host
+        transfers, 1)."""
         self._require_state()
         if self.telemetry is not None:
             obs_runtime.set_current(self.telemetry)
         if self._batched:
-            dispatch = _eng._dispatch_window_batch
-            read_counters = _eng.batch_counters
+            dispatch, unpack = _eng._dispatch_window_batch, _eng.unpack_batch
         else:
-            dispatch = _eng._dispatch_window
-            read_counters = _eng.window_counters
+            dispatch, unpack = _eng._dispatch_window, _eng.unpack
         sp = self._spans
         with sp.call("step", n=n):
             with sp.child("dispatch", walk_slots=self._walk):
-                self.state, series = dispatch(self.state, self.cfg, n,
-                                              mf=mf)
+                self.state, series, summary = dispatch(self.state, self.cfg,
+                                                       n, mf=mf)
+                summary.copy_to_host_async()
             with sp.child("wait"):
-                jax.block_until_ready(series)
+                summary.block_until_ready()
             with sp.child("readback") as span:
                 read = _eng.HostReads()
-                counters = read_counters(series, self.cfg, read)
+                counters = unpack(read(summary), _eng.series_shapes(series))
                 span.set_metadata(fetches=read.count)
         self._parts.append(counters)
         self._weights.append(n)

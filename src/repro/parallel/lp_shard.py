@@ -83,7 +83,8 @@ from repro.core.abm import (epidemic_draws, epidemic_row_update,
                             max_step_displacement, mobility_row_apply,
                             mobility_row_draws, mobility_step,
                             row_local_mobility)
-from repro.core.engine import COMPILED_CACHE_SIZE
+from repro.core.engine import (COMPILED_CACHE_SIZE, batch_summary,
+                               window_summary)
 from repro.obs import ledger as obs_ledger
 from repro.obs import runtime as obs_runtime
 
@@ -1228,7 +1229,8 @@ def _compiled_window_sharded(key_cfg, n_steps: int):
         def fn(state, mf):
             def body(s, _):
                 return step_sharded(s, key_cfg, spec, mesh, mf=mf)
-            return jax.lax.scan(body, state, None, length=n_steps)
+            state, series = jax.lax.scan(body, state, None, length=n_steps)
+            return state, series, window_summary(series)
         return jax.jit(fn)
 
     # telemetry on: same ring-drain design as engine._compiled_window,
@@ -1256,32 +1258,22 @@ def _compiled_window_sharded(key_cfg, n_steps: int):
         ring0 = jnp.full((de, n_cols), -1.0, jnp.float32)
         (s, ring), series = jax.lax.scan(body, (state, ring0), None,
                                          length=n_steps)
-        return s, ring, series
+        return s, ring, series, window_summary(series)
     return jax.jit(fn)
 
 
 def _scan_sharded(state, cfg, n_steps: int, mf=None):
+    """Returns (state, series, summary), as `engine._dispatch_window`."""
     from repro.core.engine import window_key_cfg
     mf_val = jnp.float32(cfg.heuristic.mf if mf is None else mf)
     if cfg.obs.enabled:
         t0 = int(state["t"])
-        state, ring, series = _compiled_window_sharded(
+        state, ring, series, summary = _compiled_window_sharded(
             window_key_cfg(cfg), n_steps)(state, mf_val)
         obs_runtime.flush_tail(ring, t0, t0 + n_steps)
-        return state, series
+        return state, series, summary
     return _compiled_window_sharded(window_key_cfg(cfg), n_steps)(
         state, mf_val)
-
-
-def _series_counters(series, read=np.asarray):
-    from repro.core.engine import series_counters
-    counters = series_counters(series, read)
-    counters["mean_halo_frac"] = float(read(series["halo_frac"].mean()))
-    counters["shard_overflow"] = float(read(series["shard_overflow"].sum()))
-    wf = read(series["wire_flows"]).astype(np.int64)
-    counters["bytes_on_wire"] = float(wf.sum())
-    counters["wire_flows"] = wf.sum(axis=0).tolist()
-    return counters
 
 
 def walk_slots(cfg) -> int:
@@ -1305,11 +1297,11 @@ def run_sharded(key, cfg):
     """Sharded mirror of `engine.run`: returns (final_state, series,
     counters) with the final state unsharded back to gid-order, so
     callers (and the equivalence tests) see the oracle's layout."""
-    from repro.core.engine import _migration_ratio
+    from repro.core.engine import _migration_ratio, series_shapes, unpack
     spec = make_shard_spec(cfg)
     st = init_sharded(key, cfg, spec)
-    st, series = _scan_sharded(st, cfg, cfg.timesteps)
-    counters = _series_counters(series)
+    st, series, summary = _scan_sharded(st, cfg, cfg.timesteps)
+    counters = unpack(summary, series_shapes(series))
     counters["migration_ratio"] = _migration_ratio(counters, cfg)  # Eq. 8
     return unshard_state(st, spec), series, counters
 
@@ -1330,7 +1322,8 @@ def _compiled_batch_sharded(key_cfg, n_steps: int):
     def fn(state, mfs):
         def body(s, _):
             return step_sharded_batch(s, key_cfg, spec, mesh, mfs)
-        return jax.lax.scan(body, state, None, length=n_steps)
+        state, series = jax.lax.scan(body, state, None, length=n_steps)
+        return state, series, batch_summary(series)
     return jax.jit(fn)
 
 
@@ -1341,12 +1334,6 @@ def _scan_batch_sharded(states, cfg, n_steps: int, mf=None):
     n_rep = states["t"].shape[0]
     return _compiled_batch_sharded(window_key_cfg(strip_obs(cfg)), n_steps)(
         states, _mf_vector(cfg, mf, n_rep))
-
-
-def _batch_replica_counters(series, n_rep: int):
-    from repro.core.engine import replica_series
-    return [_series_counters(replica_series(series, r))
-            for r in range(n_rep)]
 
 
 def unshard_batch(states, spec: ShardSpec):
@@ -1364,12 +1351,14 @@ def run_batch_sharded(cfg, seeds):
     each shard, final states unsharded to gid-order per replica — so
     sharded replicas compare byte-for-byte against oracle replicas."""
     from repro.core.engine import (_migration_ratio, replica_keys,
-                                   stack_states)
+                                   series_shapes, stack_states,
+                                   unpack_batch)
     spec = make_shard_spec(cfg)
     states = stack_states([init_sharded(k, cfg, spec)
                            for k in replica_keys(seeds)])
-    states, series = _scan_batch_sharded(states, cfg, cfg.timesteps)
-    reps = _batch_replica_counters(series, len(seeds))
+    states, series, summary = _scan_batch_sharded(states, cfg,
+                                                  cfg.timesteps)
+    reps = unpack_batch(summary, series_shapes(series))
     for c in reps:
         c["migration_ratio"] = _migration_ratio(c, cfg)  # Eq. 8
     return unshard_batch(states, spec), series, reps

@@ -94,19 +94,16 @@ def _globalize(state, spec, mesh):
     return out
 
 
-def _fetch_series(series):
-    """Metrics come back replicated (out_specs P()), but a global array
+def _fetch(v):
+    """The counter summary comes back replicated, but a global array
     spanning non-addressable devices refuses np.asarray; pull the local
     replica instead."""
-    import jax
     from jax.experimental import multihost_utils
     import numpy as np
 
-    def fetch(v):
-        if getattr(v, "is_fully_addressable", True):
-            return np.asarray(v)
-        return np.asarray(multihost_utils.process_allgather(v))
-    return {k: fetch(v) for k, v in series.items()}
+    if getattr(v, "is_fully_addressable", True):
+        return np.asarray(v)
+    return np.asarray(multihost_utils.process_allgather(v))
 
 
 def _probe_collectives(mesh) -> bool:
@@ -135,7 +132,7 @@ def run_distributed(args) -> int:
                                    num_processes=args.processes,
                                    process_id=args.process_id)
     import jax.numpy as jnp
-    from repro.core.engine import window_key_cfg
+    from repro.core.engine import series_shapes, unpack, window_key_cfg
     from repro.parallel import lp_shard
 
     cfg = _build_config(args)
@@ -157,11 +154,11 @@ def run_distributed(args) -> int:
         state = _globalize(state, spec, mesh)
     scan = lp_shard._compiled_window_sharded(window_key_cfg(cfg), args.steps)
     mf = jnp.float32(cfg.heuristic.mf)
-    state, series = jax.block_until_ready(scan(state, mf))  # compile+run
-    t0 = time.time()
-    state, series = jax.block_until_ready(scan(state, mf))
+    state, series, summary = jax.block_until_ready(scan(state, mf))
+    t0 = time.time()  # the first call compiled and ran
+    state, series, summary = jax.block_until_ready(scan(state, mf))
     dt = (time.time() - t0) / args.steps
-    counters = lp_shard._series_counters(_fetch_series(series))
+    counters = unpack(_fetch(summary), series_shapes(series))
     if pid == 0:
         out = dict(processes=args.processes, devices=jax.device_count(),
                    n_se=args.n_se, n_lp=args.n_lp, steps=args.steps,

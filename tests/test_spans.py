@@ -135,9 +135,8 @@ def test_call_span_tree(layer, call, tmp_path):
     if call == "step":
         assert args["n"] == STEPS
         assert kid["dispatch"]["walk_slots"] == walk_slots(cfg)
-        # one transfer per counter; `bytes_on_wire` sums the wire flows'
-        assert kid["readback"]["fetches"] == len(out) - ("bytes_on_wire"
-                                                         in out)
+        # the window program's counter summary: one transfer per window
+        assert kid["readback"]["fetches"] == 1
     elif call in ("arrive", "depart"):
         assert args["batch"] == 3 and args["padded"] == 4
     elif call == "query_neighbors":
